@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # models the pod; never takes a chip
 
 """Modeled-TPU mixed-destination table: each paper app is compiled per
 destination on the production (16,16) mesh and scored with the three-term

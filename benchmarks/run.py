@@ -211,7 +211,8 @@ def table_modeled_fig3():
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.modeled", str(out)],
         capture_output=True, text=True, timeout=900,
-        cwd=str(ROOT), env=dict(os.environ, PYTHONPATH=str(ROOT / "src")))
+        cwd=str(ROOT), env=dict(os.environ, PYTHONPATH=str(ROOT / "src"),
+                                JAX_PLATFORMS="cpu"))
     if r.returncode != 0:
         emit("modeled/error", 0.0, r.stderr[-200:].replace(",", ";"))
         return

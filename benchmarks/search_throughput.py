@@ -28,6 +28,7 @@ candidate count but not the artifact count.
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"     # models the mesh; never takes a chip
 
 import argparse
 import json

@@ -1,6 +1,7 @@
 import os
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"     # models the mesh; never takes a chip
 
 """Framework-side offload search: the paper's GA over *execution-plan*
 genes (sharding / remat / microbatching / compression) for an LM training

@@ -28,7 +28,7 @@ def main():
     archs = ([args.arch] if args.arch else
              ["granite-3-2b", "mamba2-1.3b", "recurrentgemma-2b"])
     for arch in archs:
-        flags = ["--arch", arch, "--batch", str(args.batch),
+        flags = ["--arch", arch, "--reduced", "--batch", str(args.batch),
                  "--prompt-len", "32", "--gen", str(args.gen)]
         if args.trace:
             flags += ["--trace", str(args.trace)]

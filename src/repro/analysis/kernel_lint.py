@@ -240,11 +240,10 @@ def decode_attention_model(bh: int = 8, s: int = 2048, d: int = 64, *,
                         lambda b, j: (b, j, 0)),
             OperandSpec("v_cache", (bh, s, d), (1, bkv, d),
                         lambda b, j: (b, j, 0)),
-            OperandSpec("lens", (bh, 1), (1, 1),
-                        lambda b, j: (b, 0)),
         ],
-        output=OperandSpec("o", (bh, d), (1, d),
-                           lambda b, j: (b, 0)),
+        # the cache length is a whole SMEM scalar, not a blocked operand
+        output=OperandSpec("o", (bh, 1, d), (1, 1, d),
+                           lambda b, j: (b, 0, 0)),
         accum_dims=(1,), size_tag=f"bh{bh} s{s}")
     return model, []
 
@@ -258,24 +257,26 @@ def tdfir_model(f: int = 4, n: int = 1000, k: int = 16, *,
             "K001", ERROR,
             f"tdfir: block_n {bn} < taps {k} — the sliding history cannot "
             "cover the filter, the wrapper asserts", subject="tdfir")]
-    pn = (-n) % bn
-    np_ = n + pn
+    rows = 8                         # filters per tile (tdfir.ROWS)
+    pf, pn = (-f) % rows, (-n) % bn
+    fp, np_ = f + pf, n + pn
+    padded = (0,) * (pf > 0) + (1,) * (pn > 0)
 
     def prev_map(i, j):
         return (i, max(j - 1, 0))    # wrapper uses jnp.maximum; same clamp
 
     model = KernelModel(
-        name="tdfir", grid=(f, np_ // bn),
+        name="tdfir", grid=(fp // rows, np_ // bn),
         inputs=[
-            OperandSpec("x_prev", (f, np_), (1, bn), prev_map,
-                        padded_dims=(1,) * (pn > 0)),
-            OperandSpec("x_cur", (f, np_), (1, bn),
-                        lambda i, j: (i, j),
-                        padded_dims=(1,) * (pn > 0)),
-            OperandSpec("h", (f, bn), (1, bn),
-                        lambda i, j: (i, 0)),
+            OperandSpec("x_prev", (fp, np_), (rows, bn), prev_map,
+                        padded_dims=padded),
+            OperandSpec("x_cur", (fp, np_), (rows, bn),
+                        lambda i, j: (i, j), padded_dims=padded),
+            OperandSpec("h", (fp, bn), (rows, bn),
+                        lambda i, j: (i, 0),
+                        padded_dims=(0,) * (pf > 0) + (1,) * (k < bn)),
         ],
-        output=OperandSpec("y", (f, np_), (1, bn),
+        output=OperandSpec("y", (fp, np_), (rows, bn),
                            lambda i, j: (i, j)),
         size_tag=f"f{f} n{n} k{k}")
     return model, []

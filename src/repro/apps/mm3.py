@@ -36,7 +36,7 @@ def _tp_matmul(a, b, parts: int = 4):
 
 
 def _pallas_matmul(a, b):
-    return mm_kernel.matmul(a, b, interpret=True)
+    return mm_kernel.matmul(a, b)
 
 
 def _init_nest(name, key_idx):

@@ -72,8 +72,7 @@ def _fir_xla(x, h):
 
 
 def _fir_pallas(x, h):
-    return fir_kernel.tdfir(x, h, block_n=max(128, h.shape[1]),
-                            interpret=True)
+    return fir_kernel.tdfir(x, h, block_n=max(128, h.shape[1]))
 
 
 def _fir_nest():

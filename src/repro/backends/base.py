@@ -51,6 +51,16 @@ class SearchResult:
     # loop GA's choice-keyed measurement memo; search-cache stats for
     # compiled paths) — observability only, never selection input
     cache_stats: Dict = field(default_factory=dict)
+    # the first candidate that failed to build, compile or run: its error
+    # (Evaluation.info["error"]).  The candidate still takes the paper's
+    # penalty; this keeps the failure visible on the record.
+    error: str = ""
+
+
+def first_error(evaluations) -> str:
+    """The first ``info["error"]`` among ``evaluations``, or ""."""
+    return next((ev.info["error"] for ev in evaluations
+                 if ev.info.get("error")), "")
 
 
 @dataclass
@@ -100,7 +110,7 @@ def generic_fb_search(backend: "Backend", app, ctx: SearchContext
         destination=backend.name, best_choice=dict(choice),
         best_time_s=ev.effective_time, n_measurements=1,
         verify_elapsed_s=time.perf_counter() - t0, note=note,
-        best_correct=ev.correct)
+        best_correct=ev.correct, error=first_error([ev]))
 
 
 def bridge_mesh_verify(backend: "Backend", cost_runner, fn, inputs):

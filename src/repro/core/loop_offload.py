@@ -11,7 +11,8 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional, Tuple
 
-from repro.backends.base import Backend as Destination, SearchResult
+from repro.backends.base import (Backend as Destination, SearchResult,
+                                 first_error)
 from repro.core import ga as ga_mod, intensity
 from repro.core.ga import Evaluation, GAConfig, GAResult
 from repro.core.measure import TimedRunner
@@ -70,7 +71,8 @@ def ga_search(app: OffloadableApp, dest: Destination, runner: TimedRunner,
                              penalty_s=cfg.penalty_s)
         return LoopSearchResult(dest.name, fixed_choice, ev.effective_time,
                                 1, 0.0, note="no free loops",
-                                best_correct=ev.correct)
+                                best_correct=ev.correct,
+                                error=first_error([ev]))
 
     # structural dedupe for the verification environment: distinct gene
     # strings can build the *same* offload pattern (a gene set on a nest
@@ -113,7 +115,8 @@ def ga_search(app: OffloadableApp, dest: Destination, runner: TimedRunner,
         n_measurements=res.n_measurements, verify_elapsed_s=elapsed,
         history=res.history, best_correct=res.best_eval.correct,
         cache_stats={"measured": len(measured) - pruned[0],
-                     "reused": reused[0], "static_pruned": pruned[0]})
+                     "reused": reused[0], "static_pruned": pruned[0]},
+        error=first_error(measured.values()))
 
 
 def fpga_search(app: OffloadableApp, dest: Destination, runner: TimedRunner,
@@ -172,7 +175,8 @@ def fpga_search(app: OffloadableApp, dest: Destination, runner: TimedRunner,
         return LoopSearchResult(dest.name, fixed_choice, ev.effective_time,
                                 1, elapsed, note=note,
                                 best_correct=ev.correct,
-                                cache_stats={"static_pruned": n_pruned})
+                                cache_stats={"static_pruned": n_pruned},
+                                error=first_error([ev]))
     # as in run_ga: a wrong result never wins the search outright
     correct_results = [r for r in results if r[1].correct]
     best_name, best_ev = min(correct_results or results,
@@ -188,4 +192,5 @@ def fpga_search(app: OffloadableApp, dest: Destination, runner: TimedRunner,
         best_time_s=best_ev.effective_time, n_measurements=len(results),
         verify_elapsed_s=elapsed, history=history,
         best_correct=best_ev.correct,
-        cache_stats={"static_pruned": n_pruned})
+        cache_stats={"static_pruned": n_pruned},
+        error=first_error(e for _, e in results))

@@ -71,6 +71,9 @@ class VerificationRecord:
     met_target: bool
     choice: Dict[str, str] = field(default_factory=dict)
     note: str = ""
+    # first candidate error of the search (build / compile / run failure);
+    # the candidate took the penalty, the error stays visible here
+    error: str = ""
     # False: best_time_s is the configured penalty for a wrong result /
     # timeout — kept as evidence but never pinned, selected or early-stopped
     correct: bool = True
@@ -195,13 +198,17 @@ def plan_offload(app, targets: UserTarget, *, seed: int = 0,
 
     # single-core reference (paper's "processing time by a single core");
     # the measurement already ran the function — reuse its output instead of
-    # compiling and executing the reference a second time
+    # compiling and executing the reference a second time.  It runs at full
+    # float32 matmul precision: the TPU's default multiplies float32 in one
+    # bfloat16 pass, and the result-equality check must compare candidates
+    # with the program's float32 answer, not with that approximation.
+    import jax
     ref_fn = app.reference_fn()
-    ref_eval = runner.measure(ref_fn, inputs, None)
-    ref_out = ref_eval.info.get("output")
-    if ref_out is None:
-        import jax
-        ref_out = jax.jit(ref_fn)(inputs)
+    with jax.default_matmul_precision("highest"):
+        ref_eval = runner.measure(ref_fn, inputs, None)
+        ref_out = ref_eval.info.get("output")
+        if ref_out is None:
+            ref_out = jax.jit(ref_fn)(inputs)
     ref_time = ref_eval.time_s
 
     # FB discovery once (name match + similarity), per paper [41]
@@ -249,6 +256,7 @@ def plan_offload(app, targets: UserTarget, *, seed: int = 0,
                     res.best_time_s, ref_time, backend.price),
                 correct=res.best_correct,
                 choice=dict(res.best_choice), note=res.note,
+                error=res.error,
                 cache_stats=dict(getattr(res, "cache_stats", {}) or {}))
             records.append(rec)
 

@@ -21,9 +21,6 @@ Public API (stable — later PRs build on this):
     (stage parallelism over the "pod" axis under any registered schedule).
   * :mod:`repro.dist.bridge`    — planner <-> mesh bridge: compile a
     dp / tp candidate under a real mesh via ``CompiledCostRunner``.
-  * :mod:`repro.dist.compat`    — JAX version shims (``shard_map``,
-    ``make_mesh``, ``AxisType``) so the same call sites run on the
-    installed runtime and on current JAX.
 """
 from repro.dist.plan import Plan
 from repro.dist.schedules import (SCHEDULES, Schedule, TickPlan,

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from repro.dist.compat import shard_map
 from repro.dist.schedules import get_schedule
 
 
@@ -127,8 +126,8 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, *, microbatches: int = 1,
             carry = send
         return jax.lax.psum(outs, axis)
 
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(PartitionSpec(axis), PartitionSpec()),
-                    out_specs=PartitionSpec(), axis_names={axis},
-                    check_vma=False)(ws, mb)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(PartitionSpec(axis), PartitionSpec()),
+                        out_specs=PartitionSpec(), axis_names={axis},
+                        check_vma=False)(ws, mb)
     return out.reshape(x.shape)

@@ -45,17 +45,11 @@ def batch_axes(mesh) -> Tuple[str, ...]:
 
 
 class Rules:
-    """Sharding rules for one (mesh, plan) pair.
+    """Sharding rules for one (mesh, plan) pair."""
 
-    ``exclude_axes`` removes mesh axes from every rule — used inside a
-    ``shard_map`` where those axes are Manual and the inner (Auto) sharding
-    constraints must not reference them (``train_step.py`` excludes "pod").
-    """
-
-    def __init__(self, mesh, plan=None, exclude_axes: Sequence[str] = ()):
+    def __init__(self, mesh, plan=None):
         self.mesh = mesh
         self.plan = plan
-        self.exclude_axes = tuple(exclude_axes)
         self.rules = dict(BASE_RULES)
         if plan is not None and getattr(plan, "decode_kv_seq_shard", False):
             self.rules["kv_seq"] = "model"
@@ -72,9 +66,7 @@ class Rules:
         as_tuple = isinstance(rule, tuple)
         candidates = rule if as_tuple else (rule,)
         axes = tuple(a for a in candidates
-                     if a in self.mesh.axis_names
-                     and a not in self.exclude_axes
-                     and a not in used)
+                     if a in self.mesh.axis_names and a not in used)
         if not axes:
             return None
         if dim is not None:

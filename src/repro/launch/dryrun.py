@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # models the pod; never takes a chip
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -194,8 +195,7 @@ def _run_cell(arch: str, shape_name: str, mesh_kind: str,
         t_compile = time.time() - t0
         verify_s = t_lower + t_compile
 
-        from repro.dist.compat import cost_analysis_dict
-        ca_raw = cost_analysis_dict(compiled)
+        ca_raw = compiled.cost_analysis() or {}
         ca = {k: float(v) for k, v in ca_raw.items()
               if isinstance(v, (int, float))
               and ("flops" in k or k == "bytes accessed")}

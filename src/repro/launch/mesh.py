@@ -4,15 +4,13 @@ A FUNCTION (not module-level constant) so importing never touches jax device
 state.  Single-pod: (16, 16) = 256 chips, axes ("data", "model").  Multi-pod:
 (2, 16, 16) = 512 chips, axes ("pod", "data", "model") — "pod" is the
 DCN-class axis used for cross-pod data parallelism (or pipeline stages).
-
-All constructors go through repro.dist.compat so the same code runs on the
-pinned JAX and on current JAX (axis_types only exists on the latter).
+Every axis is Auto: GSPMD places what the sharding rules leave open.
 """
 from __future__ import annotations
 
 import jax
-
-from repro.dist.compat import AxisType, make_mesh, mesh_from_devices
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,16 +22,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()
     if len(devices) > need:       # single-pod mesh on the 512-device host
         devices = devices[:need]
-    return mesh_from_devices(devices, shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
+    return Mesh(np.asarray(devices).reshape(shape), axes,
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for forced-multi-device unit tests."""
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1-D data mesh (examples/CI)."""
     n = len(jax.devices())
-    return make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

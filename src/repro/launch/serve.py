@@ -1,6 +1,7 @@
 """Serving driver: continuous-batching engine over one model replica.
 
-CPU-runnable with reduced configs.  ``generate`` remains the sequential
+Runs the published config by default; ``--reduced`` opts into the tiny
+same-family config for the CPU.  ``generate`` remains the sequential
 batch reference (prefill + greedy decode, jits memoized per model so
 repeated calls never re-trace); the CLI routes through
 :class:`repro.serve.ContinuousBatcher`, where requests join and leave the
@@ -86,10 +87,11 @@ def synthetic_trace(cfg, n: int, prompt_len: int, gen: int, *,
     return reqs
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (CPU smoke runs)")
     ap.add_argument("--batch", type=int, default=4,
                     help="slot-pool width (concurrent requests)")
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -97,23 +99,41 @@ def main(argv=None):
     ap.add_argument("--trace", type=int, default=0, metavar="N",
                     help="serve a synthetic open-loop trace of N staggered "
                          "arrivals instead of one gang batch")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def config_for(args):
+    """The model config a parsed command line serves."""
     from repro.configs import get_config
+    cfg = get_config(args.arch)
+    return cfg.reduced() if args.reduced else cfg
+
+
+def build_engine(cfg, *, n_slots: int, cache_len: int, seed: int = 0):
+    """One replica: random parameters from ``seed``, initialised under
+    ``jit`` (on the device, never materialised on the host), behind a
+    :class:`repro.serve.ContinuousBatcher`."""
     from repro.models.lm import Model
     from repro.power import envelope_for
-    from repro.serve import ContinuousBatcher, Request
+    from repro.serve import ContinuousBatcher
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
+    return ContinuousBatcher(model, params, n_slots=n_slots,
+                             cache_len=cache_len,
+                             envelope=envelope_for(None))
 
-    cache_len = args.prompt_len + args.gen
-    engine = ContinuousBatcher(model, params, n_slots=args.batch,
-                               cache_len=cache_len,
-                               envelope=envelope_for(None))
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    args = parse_args(argv)
+
+    from repro.serve import Request
+
+    cfg = config_for(args)
+    engine = build_engine(cfg, n_slots=args.batch,
+                          cache_len=args.prompt_len + args.gen)
     if args.trace:
         reqs = synthetic_trace(cfg, args.trace, args.prompt_len, args.gen)
     else:

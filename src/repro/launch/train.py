@@ -1,29 +1,16 @@
 """End-to-end training driver.
 
-CPU-runnable with reduced configs (``--reduced``), production-structured:
-mesh + sharded jit train step, deterministic data pipeline, fault-tolerant
-checkpointed loop, straggler watchdog, optional int8 cross-pod gradient
-compression (``--pod-parallel --compress``).
-
-On a real TPU pod, launch per-host with the same flags; the XLA flags below
-enable async collectives + latency-hiding scheduling (no-ops on CPU).
+Runs the published config by default; ``--reduced`` opts into the tiny
+same-family config for the CPU.  Production-structured: mesh + sharded jit
+train step, deterministic data pipeline, fault-tolerant checkpointed loop,
+straggler watchdog.  ``--pod-parallel --compress`` (int8 cross-pod
+gradient compression) needs a mesh with a "pod" axis and raises on the
+1-D host mesh this driver builds.
 
   PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b \
       --reduced --steps 100 --batch 8 --seq 128
 """
 from __future__ import annotations
-
-import os
-
-TPU_XLA_FLAGS = " ".join([
-    "--xla_enable_async_collective_permute=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_latency_hiding_scheduler_rerun=2",
-])
-if os.environ.get("REPRO_TPU"):
-    os.environ["LIBTPU_INIT_ARGS"] = os.environ.get(
-        "LIBTPU_INIT_ARGS", "") + " " + TPU_XLA_FLAGS
 
 import argparse
 import time
@@ -32,7 +19,46 @@ import jax
 import jax.numpy as jnp
 
 
+def build_training(cfg, plan, tcfg, mesh):
+    """Jitted train step and state initialiser for a sharded model.
+
+    Parameters shard per the plan's :class:`~repro.dist.sharding.Rules`
+    (FSDP: ``embed`` over ``data``) and the optimizer state mirrors them;
+    both are created under ``jit`` with those output shardings, so no
+    device ever holds the whole state.  Returns ``(jstep, init_state)``;
+    ``jstep`` donates params and optimizer state.
+    """
+    from repro.dist.sharding import Rules, tree_shardings
+    from repro.models.lm import Model, param_axes
+    from repro.train import optimizer, train_step as ts
+
+    rules = Rules(mesh, plan)
+    model = Model(cfg, plan, rules)
+    p_axes = param_axes(cfg)
+    params_sds = jax.eval_shape(model.init,
+                                jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params_sh = tree_shardings(rules, p_axes, params_sds)
+
+    def opt_init(params):
+        return optimizer.init(params, tcfg)
+
+    opt_sh = tree_shardings(rules, optimizer.opt_state_axes(p_axes, tcfg),
+                            jax.eval_shape(opt_init, params_sds))
+
+    jstep = jax.jit(ts.make_train_step(model, tcfg), donate_argnums=(0, 1))
+
+    def init_state():
+        params = jax.jit(model.init, out_shardings=params_sh)(
+            jax.random.PRNGKey(tcfg.seed))
+        opt = jax.jit(opt_init, out_shardings=opt_sh)(params)
+        return {"params": params, "opt": opt}
+
+    return jstep, init_state
+
+
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b")
     ap.add_argument("--reduced", action="store_true")
@@ -54,13 +80,15 @@ def main(argv=None):
     from repro.configs.base import ShapeConfig, TrainConfig
     from repro.data.pipeline import SyntheticTokens, data_config_for
     from repro.dist.plan import Plan
-    from repro.dist.sharding import Rules
     from repro.checkpoint.checkpointer import Checkpointer
     from repro.launch.mesh import make_host_mesh
-    from repro.models.lm import Model, param_axes
     from repro.runtime.fault_tolerance import run_resilient
-    from repro.train import optimizer, train_step as ts
-    from repro.dist.sharding import tree_shardings
+
+    mesh = make_host_mesh()
+    if args.pod_parallel and "pod" not in mesh.axis_names:
+        raise ValueError(
+            f"--pod-parallel needs a mesh with a 'pod' axis; the host mesh "
+            f"has axes {mesh.axis_names}")
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -74,31 +102,9 @@ def main(argv=None):
                        warmup_steps=max(args.steps // 10, 1),
                        microbatches=args.microbatches)
 
-    mesh = make_host_mesh()
-    rules = Rules(mesh, plan)
-    model = Model(cfg, plan, rules)
-
-    dcfg = data_config_for(cfg, shape)
-    data = SyntheticTokens(dcfg)
-
-    p_axes = param_axes(cfg)
-    params_sds = jax.eval_shape(model.init,
-                                jax.ShapeDtypeStruct((2,), jnp.uint32))
-    params_sh = tree_shardings(rules, p_axes, params_sds)
-
-    if args.pod_parallel and "pod" in mesh.axis_names:
-        step_fn_raw = ts.make_pod_parallel_train_step(model, tcfg, mesh)
-    else:
-        step_fn_raw = ts.make_train_step(model, tcfg)
-    jstep = jax.jit(step_fn_raw, donate_argnums=(0, 1))
-
+    jstep, init_state = build_training(cfg, plan, tcfg, mesh)
+    data = SyntheticTokens(data_config_for(cfg, shape))
     ckpt = Checkpointer(args.ckpt_dir, keep=2)
-
-    def init_state():
-        params = jax.jit(model.init, out_shardings=params_sh)(
-            jax.random.PRNGKey(tcfg.seed))
-        opt = optimizer.init(params, tcfg)
-        return {"params": params, "opt": opt}
 
     def body(state, step):
         batch = data.batch(step)

@@ -167,7 +167,6 @@ def apply_moe_ep(p, cfg, x, rules, capacity_factor=None):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
     from repro.dist.sharding import batch_axes
 
     m = cfg.moe
@@ -238,7 +237,7 @@ def apply_moe_ep(p, cfg, x, rules, capacity_factor=None):
 
     experts_spec = jax.tree.map(lambda _: P("model"), p["experts"])
     manual = set(dp) | {"model"}
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp), P(), experts_spec),
         out_specs=(P(dp), P()),

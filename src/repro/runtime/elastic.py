@@ -65,16 +65,17 @@ def reshard_restore(ckpt: Checkpointer, *, step: Optional[int],
 def available_mesh(preferred_shape=None, axes=("data", "model")):
     """Best mesh for the devices that are actually alive (elastic restart
     after losing a slice): largest power-of-two data axis x rest."""
-    from repro.dist.compat import AxisType, mesh_from_devices
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
     n = len(jax.devices())
+    auto = (AxisType.Auto,) * len(axes)
     if preferred_shape is not None:
         need = 1
         for s in preferred_shape:
             need *= s
         if need <= n:
-            return mesh_from_devices(
-                jax.devices()[:need], preferred_shape, axes,
-                axis_types=(AxisType.Auto,) * len(axes))
+            devs = np.asarray(jax.devices()[:need]).reshape(preferred_shape)
+            return Mesh(devs, axes, axis_types=auto)
     # fall back: 1-D data mesh over whatever is left
-    return mesh_from_devices(jax.devices(), (n, 1), axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
+    return Mesh(np.asarray(jax.devices()).reshape(n, 1), axes,
+                axis_types=auto)

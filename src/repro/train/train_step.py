@@ -81,19 +81,17 @@ def make_pod_parallel_train_step(model: Model, tcfg: TrainConfig,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist import compat
-    from repro.dist.compat import shard_map
-    from repro.dist.sharding import NullRules, Rules
+    from repro.dist.sharding import NullRules
     from repro.models.lm import Model
 
-    # inside the pod shard_map the "pod" axis is Manual: the inner model's
-    # sharding rules must only reference the remaining (Auto) axes — and on
-    # JAX/XLA too old for partial-manual constraints they are dropped
-    # entirely (a layout hint, not semantics; GSPMD still propagates the
-    # in_specs shardings)
-    inner_rules = (Rules(mesh, model.plan, exclude_axes=("pod",))
-                   if compat.PARTIAL_MANUAL_CONSTRAINTS else NullRules())
-    inner_model = Model(model.cfg, model.plan, inner_rules)
+    # inside the pod shard_map the "pod" axis is Manual and the inner model
+    # runs without layout constraints: GSPMD propagates the data/model
+    # shardings from the arguments.  Constraints are hints, not semantics,
+    # and the installed XLA cannot partition one of them there — the
+    # cross-entropy's vocab-sharded ("batch", None, "vocab") logits inside
+    # the pod-manual region fail its SPMD partitioner's device-group check
+    # (spmd_partitioner_util.cc), while every other rule compiles.
+    inner_model = Model(model.cfg, model.plan, NullRules())
     loss_fn = make_loss_fn(inner_model)
     compress = model.plan.grad_compression
 
@@ -123,7 +121,7 @@ def make_pod_parallel_train_step(model: Model, tcfg: TrainConfig,
             return jax.tree.map(lambda _: P(), tree)
 
         shard_batch = jax.tree.map(lambda _: P("pod"), batch)
-        grads, new_ef, loss, metrics = shard_map(
+        grads, new_ef, loss, metrics = jax.shard_map(
             pod_body, mesh=mesh,
             in_specs=(rep(params), rep(ef), shard_batch),
             out_specs=(rep(params), rep(ef), P(), rep({"loss": 0,

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.dist.plan import Plan
 from repro.dist.sharding import (NullRules, Rules, batch_axes,
                                  tree_shardings)
@@ -18,8 +18,8 @@ from repro.dist.sharding import (NullRules, Rules, batch_axes,
 
 @pytest.fixture(scope="module")
 def mesh():
-    return make_mesh((1, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # ------------------------------------------------------------------- plan
@@ -105,18 +105,9 @@ def test_rules_duplicate_axis_falls_back():
                       dims=(8, 32, 8, 4)) == P(("data",), "model")
 
 
-def test_rules_exclude_axes():
-    class FakeMesh:
-        axis_names = ("pod", "data", "model")
-        shape = {"pod": 2, "data": 2, "model": 2}
-    rules = Rules(FakeMesh(), Plan(), exclude_axes=("pod",))
-    # batch normally rides ("pod", "data"); with pod Manual it must not
-    assert rules.spec(("batch", None), dims=(8, 4)) == P(("data",))
-
-
 def test_batch_axes(mesh):
     assert batch_axes(mesh) == ("data",)
-    pod_mesh = make_mesh((1, 1), ("pod", "data"))
+    pod_mesh = jax.make_mesh((1, 1), ("pod", "data"))
     assert batch_axes(pod_mesh) == ("pod", "data")
 
 

@@ -24,8 +24,7 @@ def test_scanned_matmul_flops_scaled_by_trip_count():
     # fwd + bwd(2x) = 3x fwd, within 40% (elementwise + loss noise)
     assert fwd * 2.0 < res["flops"] < fwd * 4.5, res["flops"]
     # XLA's own counter misses the loop factor
-    from repro.dist.compat import cost_analysis_dict
-    xla = cost_analysis_dict(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert res["flops"] > 2.5 * xla
 
 

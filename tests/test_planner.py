@@ -75,3 +75,36 @@ def test_selected_is_fastest(tdfir_report):
               if r.best_time_s < float("inf")]
     assert tdfir_report.selected.best_time_s == \
         min(r.best_time_s for r in finite)
+
+
+def test_candidate_compile_error_stays_on_record():
+    """A candidate whose build raises takes the paper's penalty, and its
+    error stays visible on the VerificationRecord."""
+    import jax.numpy as jnp
+
+    from repro.core.offloadable import LoopNest, OffloadableApp
+
+    def broken(state):
+        raise ValueError("kernel refused by the compiler")
+
+    def double(state):
+        return dict(state, out=state["x"] * 2.0)
+
+    app = OffloadableApp(
+        name="broken-kernel",
+        nests=[LoopNest("scale", {"seq": double, "dp": double,
+                                  "pallas": broken})],
+        make_inputs=lambda seed=0, small=False: {
+            "x": jnp.arange(8 if small else 64, dtype=jnp.float32)})
+    report = plan_offload(app, UserTarget(), runner=TimedRunner(repeats=1),
+                          ga_cfg=GAConfig(population=2, generations=2,
+                                          seed=0))
+    fpga = [r for r in report.records
+            if r.paper_analogue == "FPGA" and r.method == "loop"]
+    assert len(fpga) == 1
+    assert not fpga[0].correct
+    assert "kernel refused by the compiler" in fpga[0].error
+    # the working destinations carry no error and one of them is selected
+    assert report.selected is not None and not report.selected.error
+    assert all(not r.error for r in report.records
+               if r.paper_analogue != "FPGA")

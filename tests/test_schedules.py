@@ -12,7 +12,7 @@ import pytest
 
 from repro.core import cost_model
 from repro.core.ga import Evaluation, GAConfig, run_ga
-from repro.dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.dist.plan import Plan
 from repro.dist.schedules import (SCHEDULES, Schedule, get_schedule,
                                   register_schedule)
@@ -146,7 +146,7 @@ def test_single_rank_pod_mesh_runs_every_schedule():
     the interleaved recirculation buffer) in-process."""
     from repro.dist.pipeline import pipeline_apply, sequential_apply
 
-    mesh = make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     S, B, D = 3, 4, 8
     ws = jax.random.normal(jax.random.PRNGKey(0), (S, D, D)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(1), (B, D))
@@ -172,8 +172,8 @@ def test_single_rank_pod_mesh_runs_every_schedule():
 def test_unknown_schedule_and_bad_shapes_fall_back():
     from repro.dist.pipeline import pipeline_apply, sequential_apply
 
-    mesh = make_mesh((1, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ws = jax.random.normal(jax.random.PRNGKey(0), (3, 8, 8)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8))
 
