@@ -1,0 +1,11 @@
+"""Device: share of the traced window in which no operation ran on the
+chip while the planner worked, 1 - busy / window (device trace).  Moves
+plan_s."""
+from bench.harness import trace as tr
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    v = tr.idle_share(run.trace)
+    return None if v is None else 100.0 * v
