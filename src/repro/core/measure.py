@@ -25,6 +25,7 @@ import numpy as np
 from repro.core.ga import Evaluation
 from repro.core import cost_model
 from repro.core.search_cache import analyze_compiled
+from repro.obs import get_tracer
 
 
 def outputs_close(a, b, rtol=1e-2, atol=1e-2) -> bool:
@@ -69,29 +70,45 @@ class TimedRunner:
         ``reference_out=None`` means "this IS the reference run": the result
         is trivially correct and callers reuse ``info["output"]`` instead of
         executing the reference a second time (see planner.plan_offload).
+
+        Spans (repro.obs): ``measure`` holds ``first_call`` (jit trace,
+        lowering, compile or compile-cache load, and the first run),
+        ``repeats`` (the timed runs) and ``compare`` (the result check).
         """
+        tracer = get_tracer()
+        with tracer.span("measure", cat="plan", track="planner",
+                         reference=reference_out is None) as span:
+            ev = self._measure(tracer, fn, inputs, reference_out)
+            span.set(correct=ev.correct, timed_out=ev.timed_out)
+        return ev
+
+    def _measure(self, tracer, fn, inputs, reference_out) -> Evaluation:
         jfn = jax.jit(fn)
         try:
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(jfn(inputs))      # compile + run
-            first = time.perf_counter() - t0
+            with tracer.span("first_call", cat="plan", track="planner"):
+                t0 = time.perf_counter()
+                out = jax.block_until_ready(jfn(inputs))  # compile + run
+                first = time.perf_counter() - t0
             if first > self.timeout_s:
                 return Evaluation(time_s=first, correct=False,
                                   timed_out=True)
             times = []
-            for _ in range(self.repeats):
-                # every call gets the budget, not only the first: a
-                # candidate whose steady-state repeats hang must die
-                # through the paper's penalty path instead of running
-                # unbounded (per-call, so a legitimately slow-but-correct
-                # candidate under timeout_s per run is still measured)
-                t0 = time.perf_counter()
-                out = jax.block_until_ready(jfn(inputs))
-                dt = time.perf_counter() - t0
-                if dt > self.timeout_s:
-                    return Evaluation(time_s=dt, correct=False,
-                                      timed_out=True)
-                times.append(dt)
+            with tracer.span("repeats", cat="plan", track="planner",
+                             n=self.repeats):
+                for _ in range(self.repeats):
+                    # every call gets the budget, not only the first: a
+                    # candidate whose steady-state repeats hang must die
+                    # through the paper's penalty path instead of running
+                    # unbounded (per-call, so a legitimately
+                    # slow-but-correct candidate under timeout_s per run
+                    # is still measured)
+                    t0 = time.perf_counter()
+                    out = jax.block_until_ready(jfn(inputs))
+                    dt = time.perf_counter() - t0
+                    if dt > self.timeout_s:
+                        return Evaluation(time_s=dt, correct=False,
+                                          timed_out=True)
+                    times.append(dt)
             if reference_out is None:
                 # reference run: keep the output for reuse; candidate runs
                 # drop it (the GA cache would otherwise pin one output-sized
@@ -99,7 +116,9 @@ class TimedRunner:
                 return Evaluation(time_s=min(times), correct=True,
                                   info={"first_call_s": first,
                                         "output": out})
-            correct = outputs_close(out, reference_out, self.rtol, self.atol)
+            with tracer.span("compare", cat="plan", track="planner"):
+                correct = outputs_close(out, reference_out, self.rtol,
+                                        self.atol)
             return Evaluation(time_s=min(times), correct=correct,
                               info={"first_call_s": first})
         except Exception as e:   # compile error == paper's "conversion fails"

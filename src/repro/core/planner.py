@@ -188,147 +188,152 @@ def plan_offload(app, targets: UserTarget, *, seed: int = 0,
     or compiling.  Search stays the slow offline path; the lookup is the
     hot one.
     """
-    runner = runner or TimedRunner()
-    backends = backends if backends is not None else default_registry()
-    pol = get_policy(policy)
-    if inputs is None:
-        inputs = app.make_inputs(seed=seed)
-    if small_state is None:
-        small_state = app.make_inputs(seed=seed, small=True)
+    # one span over the whole call, the reference measurement included
+    with get_tracer().span("offload", cat="plan", track="planner",
+                           app=app.name) as plan_span:
+        runner = runner or TimedRunner()
+        backends = backends if backends is not None else default_registry()
+        pol = get_policy(policy)
+        if inputs is None:
+            inputs = app.make_inputs(seed=seed)
+        if small_state is None:
+            small_state = app.make_inputs(seed=seed, small=True)
 
-    # single-core reference (paper's "processing time by a single core");
-    # the measurement already ran the function — reuse its output instead of
-    # compiling and executing the reference a second time.  It runs at full
-    # float32 matmul precision: the TPU's default multiplies float32 in one
-    # bfloat16 pass, and the result-equality check must compare candidates
-    # with the program's float32 answer, not with that approximation.
-    import jax
-    ref_fn = app.reference_fn()
-    with jax.default_matmul_precision("highest"):
-        ref_eval = runner.measure(ref_fn, inputs, None)
-        ref_out = ref_eval.info.get("output")
-        if ref_out is None:
-            ref_out = jax.jit(ref_fn)(inputs)
-    ref_time = ref_eval.time_s
+        # single-core reference (paper's "processing time by a single
+        # core"); the measurement already ran the function — reuse its
+        # output instead of compiling and executing the reference a second
+        # time.  It runs at full float32 matmul precision: the TPU's default
+        # multiplies float32 in one bfloat16 pass, and the result-equality
+        # check must compare candidates with the program's float32 answer,
+        # not with that approximation.
+        import jax
+        ref_fn = app.reference_fn()
+        with jax.default_matmul_precision("highest"):
+            ref_eval = runner.measure(ref_fn, inputs, None)
+            ref_out = ref_eval.info.get("output")
+            if ref_out is None:
+                ref_out = jax.jit(ref_fn)(inputs)
+        ref_time = ref_eval.time_s
+        plan_span.set(ref_time_s=ref_time)
 
-    # FB discovery once (name match + similarity), per paper [41]
-    matches = function_blocks.detect(
-        app, small_state, registry=registry or function_blocks.REGISTRY)
+        # FB discovery once (name match + similarity), per paper [41]
+        matches = function_blocks.detect(
+            app, small_state, registry=registry or function_blocks.REGISTRY)
 
-    ctx = SearchContext(
-        runner=runner, inputs=inputs, ref_out=ref_out,
-        small_state=small_state, ga_cfg=ga_cfg,
-        # one penalty scale for every verification in this run (GA-internal
-        # evaluations get it via run_ga; direct measurements get it stamped)
-        penalty_s=ga_cfg.penalty_s if ga_cfg is not None else None,
-        seed=seed, fb_matches=matches, lint_choice=lint_choice)
+        ctx = SearchContext(
+            runner=runner, inputs=inputs, ref_out=ref_out,
+            small_state=small_state, ga_cfg=ga_cfg,
+            # one penalty scale for every verification in this run
+            # (GA-internal evaluations get it via run_ga; direct
+            # measurements get it stamped)
+            penalty_s=ga_cfg.penalty_s if ga_cfg is not None else None,
+            seed=seed, fb_matches=matches, lint_choice=lint_choice)
 
-    records: List[VerificationRecord] = []
-    fb_pinned = False                   # residual rule state
-    early = False
-    plan_span = get_tracer().span("offload", cat="plan", track="planner",
-                                  app=app.name, ref_time_s=ref_time)
+        records: List[VerificationRecord] = []
+        fb_pinned = False                   # residual rule state
+        early = False
 
-    for order, (backend, method) in enumerate(backends.verification_order(),
-                                              start=1):
-        # residual rule: before the FIRST loop verification, pin the best
-        # FB pattern found by the FB verifications — regardless of how they
-        # exited (a no-match FPGA FB verification must not skip the pinning
-        # of a many-core / GPU FB win).
-        if method == "loop" and not fb_pinned:
-            fb_pinned = True
-            ctx.fixed_choice = _pin_best_fb(records, ref_time)
+        for order, (backend, method) in enumerate(
+                backends.verification_order(), start=1):
+            # residual rule: before the FIRST loop verification, pin the
+            # best FB pattern found by the FB verifications — regardless of
+            # how they exited (a no-match FPGA FB verification must not skip
+            # the pinning of a many-core / GPU FB win).
+            if method == "loop" and not fb_pinned:
+                fb_pinned = True
+                ctx.fixed_choice = _pin_best_fb(records, ref_time)
 
-        with get_tracer().span("verify", cat="plan",
-                               track=f"backend:{backend.name}",
-                               backend=backend.name, method=method,
-                               order=order) as vspan:
-            res = backend.search(app, ctx, method=method)
-            rec = VerificationRecord(
-                order=order, destination=backend.name,
-                paper_analogue=backend.paper_analogue, method=method,
-                best_time_s=res.best_time_s,
-                improvement=ref_time / max(res.best_time_s, 1e-12)
-                if res.best_time_s < float("inf") else 0.0,
-                price=backend.price, n_measurements=res.n_measurements,
-                verify_elapsed_s=res.verify_elapsed_s,
-                met_target=res.best_correct and targets.met(
-                    res.best_time_s, ref_time, backend.price),
-                correct=res.best_correct,
-                choice=dict(res.best_choice), note=res.note,
-                error=res.error,
-                cache_stats=dict(getattr(res, "cache_stats", {}) or {}))
-            records.append(rec)
+            with get_tracer().span("verify", cat="plan",
+                                   track=f"backend:{backend.name}",
+                                   backend=backend.name, method=method,
+                                   order=order) as vspan:
+                res = backend.search(app, ctx, method=method)
+                rec = VerificationRecord(
+                    order=order, destination=backend.name,
+                    paper_analogue=backend.paper_analogue, method=method,
+                    best_time_s=res.best_time_s,
+                    improvement=ref_time / max(res.best_time_s, 1e-12)
+                    if res.best_time_s < float("inf") else 0.0,
+                    price=backend.price, n_measurements=res.n_measurements,
+                    verify_elapsed_s=res.verify_elapsed_s,
+                    met_target=res.best_correct and targets.met(
+                        res.best_time_s, ref_time, backend.price),
+                    correct=res.best_correct,
+                    choice=dict(res.best_choice), note=res.note,
+                    error=res.error,
+                    cache_stats=dict(getattr(res, "cache_stats", {}) or {}))
+                records.append(rec)
 
-            # mesh bridge: compile the winner for an actual mesh through
-            # the backend's hook and record the modeled (roofline) step
-            # time next to the host timing
-            if (cost_runner is not None and rec.correct
-                    and rec.best_time_s < float("inf")):
-                mesh_ev = backend.mesh_verify(
-                    cost_runner, app.build(dict(rec.choice)), inputs)
-                if mesh_ev is not None and mesh_ev.correct:
-                    rec.mesh_time_s = mesh_ev.time_s
-                    rec.mesh_info = dict(mesh_ev.info)
+                # mesh bridge: compile the winner for an actual mesh through
+                # the backend's hook and record the modeled (roofline) step
+                # time next to the host timing
+                if (cost_runner is not None and rec.correct
+                        and rec.best_time_s < float("inf")):
+                    mesh_ev = backend.mesh_verify(
+                        cost_runner, app.build(dict(rec.choice)), inputs)
+                    if mesh_ev is not None and mesh_ev.correct:
+                        rec.mesh_time_s = mesh_ev.time_s
+                        rec.mesh_info = dict(mesh_ev.info)
 
-            # energy charge (repro.power): every correct finite record gets
-            # the modeled joules/watts the power/edp policies and the
-            # power_budget_w constraint consume — from the mesh roofline
-            # when the bridge recorded one, envelope × host-time otherwise
-            if rec.correct and rec.best_time_s < float("inf"):
-                from repro.power import energy_for_record, envelope_for
-                e_rep = energy_for_record(rec, envelope_for(backend))
-                if e_rep is not None:
-                    rec.energy_j = e_rep.energy_j
-                    rec.avg_watts = e_rep.avg_watts
-                    rec.energy_info = e_rep.to_dict()
+                # energy charge (repro.power): every correct finite record
+                # gets the modeled joules/watts the power/edp policies and
+                # the power_budget_w constraint consume — from the mesh
+                # roofline when the bridge recorded one, envelope ×
+                # host-time otherwise
+                if rec.correct and rec.best_time_s < float("inf"):
+                    from repro.power import energy_for_record, envelope_for
+                    e_rep = energy_for_record(rec, envelope_for(backend))
+                    if e_rep is not None:
+                        rec.energy_j = e_rep.energy_j
+                        rec.avg_watts = e_rep.avg_watts
+                        rec.energy_info = e_rep.to_dict()
 
-            # search/lookup split: publish this verification into the
-            # serve-time lookup (correct mesh-verified records warm it;
-            # incorrect ones are recorded failures the router statically
-            # refuses)
-            if publish is not None:
-                from repro.core.plan_lookup import publish_record
-                publish_record(publish, rec, backend, app.name)
+                # search/lookup split: publish this verification into the
+                # serve-time lookup (correct mesh-verified records warm it;
+                # incorrect ones are recorded failures the router statically
+                # refuses)
+                if publish is not None:
+                    from repro.core.plan_lookup import publish_record
+                    publish_record(publish, rec, backend, app.name)
 
-            stats = rec.cache_stats
-            vspan.set(best_time_s=rec.best_time_s, correct=rec.correct,
-                      compile_s=float(stats.get("compile_s",
-                                                rec.verify_elapsed_s)),
-                      cache_hit=bool(stats.get("reused")
-                                     or stats.get("hits")
-                                     or stats.get("disk_hits")),
-                      energy_j=rec.energy_j,
-                      n_measurements=rec.n_measurements,
-                      met_target=rec.met_target)
+                stats = rec.cache_stats
+                vspan.set(best_time_s=rec.best_time_s, correct=rec.correct,
+                          compile_s=float(stats.get("compile_s",
+                                                    rec.verify_elapsed_s)),
+                          cache_hit=bool(stats.get("reused")
+                                         or stats.get("hits")
+                                         or stats.get("disk_hits")),
+                          energy_j=rec.energy_j,
+                          n_measurements=rec.n_measurements,
+                          met_target=rec.met_target)
 
-        if rec.met_target:
-            early = True
-            break
+            if rec.met_target:
+                early = True
+                break
 
-    # selection: delegated to the policy via the Candidate contract
-    # (repro.core.candidates); every policy ranks correct patterns only — a
-    # penalized wrong result is never the chosen destination (it stays in
-    # records as evidence).  Candidates quack like records and delegate
-    # unknown reads to the wrapped record, so a custom policy written
-    # against record fields ranks them unchanged; unwrap() maps the winner
-    # back to the actual VerificationRecord (PlanReport.summary_rows
-    # compares by identity).  The constraint kwargs are only passed when
-    # set: a custom policy written against the pre-constraint
-    # select(records) signature keeps working until someone actually asks
-    # it for a constrained selection.
-    from repro.core.candidates import candidates_from_records, unwrap
-    cands = candidates_from_records(records, arch=app.name)
-    if power_budget_w is not None or max_slowdown is not None:
-        selected = unwrap(pol.select(cands, power_budget_w=power_budget_w,
-                                     max_slowdown=max_slowdown))
-    else:
-        selected = unwrap(pol.select(cands))
-    plan_span.set(policy=pol.name, early_stopped=early,
-                  n_verifications=len(records),
-                  selected=selected.destination
-                  if selected is not None else None)
-    plan_span.finish()
-    return PlanReport(app=app.name, ref_time_s=ref_time, records=records,
-                      selected=selected, early_stopped=early,
-                      policy=pol.name)
+        # selection: delegated to the policy via the Candidate contract
+        # (repro.core.candidates); every policy ranks correct patterns only
+        # — a penalized wrong result is never the chosen destination (it
+        # stays in records as evidence).  Candidates quack like records and
+        # delegate unknown reads to the wrapped record, so a custom policy
+        # written against record fields ranks them unchanged; unwrap() maps
+        # the winner back to the actual VerificationRecord
+        # (PlanReport.summary_rows compares by identity).  The constraint
+        # kwargs are only passed when set: a custom policy written against
+        # the pre-constraint select(records) signature keeps working until
+        # someone actually asks it for a constrained selection.
+        from repro.core.candidates import candidates_from_records, unwrap
+        cands = candidates_from_records(records, arch=app.name)
+        if power_budget_w is not None or max_slowdown is not None:
+            selected = unwrap(pol.select(
+                cands, power_budget_w=power_budget_w,
+                max_slowdown=max_slowdown))
+        else:
+            selected = unwrap(pol.select(cands))
+        plan_span.set(policy=pol.name, early_stopped=early,
+                      n_verifications=len(records),
+                      selected=selected.destination
+                      if selected is not None else None)
+        return PlanReport(app=app.name, ref_time_s=ref_time,
+                          records=records, selected=selected,
+                          early_stopped=early, policy=pol.name)

@@ -24,7 +24,12 @@ Design constraints, all load-bearing:
     (:meth:`Tracer.set_time`), so a :class:`~repro.runtime.control
     .ControlLoop` replay produces a **byte-identical** JSONL log — the
     same determinism the control loop itself guarantees (pinned in
-    tests/test_control.py).
+    tests/test_control.py);
+  * **the profiler's clock** — a tracer built with ``annotate`` also
+    enters ``annotate(name)`` for each span and exits it when the span
+    finishes; with ``jax.profiler.TraceAnnotation`` (see
+    :func:`repro.obs.profiler.profiler_tracer`) every span lands on the
+    profiler trace's host plane, on the same clock as the device's ops.
 """
 from __future__ import annotations
 
@@ -57,11 +62,11 @@ class Span:
     """
 
     __slots__ = ("tracer", "id", "parent", "name", "cat", "track",
-                 "t0", "t1", "attrs")
+                 "t0", "t1", "attrs", "annotation")
 
     def __init__(self, tracer: "Tracer", sid: int, parent: Optional[int],
                  name: str, cat: str, track: str, t0: float,
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], annotation=None):
         self.tracer = tracer
         self.id = sid
         self.parent = parent
@@ -71,6 +76,7 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self.annotation = annotation     # entered profiler annotation
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -80,6 +86,8 @@ class Span:
         if self.t1 is not None:
             return                       # already recorded
         self.t1 = float(t) if t is not None else self.tracer.now()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         self.tracer._record_span(self)
 
     def __enter__(self) -> "Span":
@@ -148,14 +156,19 @@ class Tracer:
     ``clock`` supplies timestamps (default ``time.perf_counter``);
     :meth:`set_time` overrides it with a pinned virtual time — the
     serve/control loop pins each tick, so replays are byte-identical.
+    ``annotate`` (e.g. ``jax.profiler.TraceAnnotation``) maps a span's
+    name to a context manager that is entered when the span opens and
+    exited when it finishes; virtual-clock users leave it unset.
     Records accumulate in memory in completion order; export them with
     :meth:`to_jsonl` / :meth:`to_chrome` / :meth:`summary`.
     """
 
     enabled = True
 
-    def __init__(self, clock: Optional[Callable[[], float]] = None):
+    def __init__(self, clock: Optional[Callable[[], float]] = None,
+                 annotate: Optional[Callable[[str], Any]] = None):
         self.clock = clock if clock is not None else time.perf_counter
+        self.annotate = annotate
         self.records: List[dict] = []
         self._lock = threading.Lock()
         self._seq = 0
@@ -190,9 +203,13 @@ class Tracer:
         """Open a span; close it via context manager or ``finish()``."""
         stack = self._stack()
         parent = stack[-1] if stack else None
+        annotation = None
+        if self.annotate is not None:
+            annotation = self.annotate(name)
+            annotation.__enter__()
         sp = Span(self, self._next_id(), parent, name, cat, track,
                   float(t0) if t0 is not None else self.now(),
-                  dict(attrs))
+                  dict(attrs), annotation)
         stack.append(sp.id)
         return sp
 
@@ -222,8 +239,11 @@ class Tracer:
 
     def event(self, name: str, cat: str = "", track: str = "",
               t: Optional[float] = None, **attrs) -> dict:
-        """Record an instant event."""
+        """Record an instant event; its ``parent`` is the innermost span
+        open on this thread."""
+        stack = self._stack()
         rec = {"type": "event", "id": self._next_id(), "name": name,
+               "parent": stack[-1] if stack else None,
                "cat": cat, "track": track,
                "t": float(t) if t is not None else self.now(),
                "attrs": _jsonable(attrs)}

@@ -26,6 +26,15 @@ Slot-pool invariants (the ROADMAP contract):
 Time is a virtual tick clock (``tick_s`` per engine tick): arrivals,
 TTFT/TPOT and the continuous-vs-static comparison all live on one
 deterministic timeline, independent of host load.
+
+Spans (:mod:`repro.obs`, on the ambient tracer's own clock; no-ops unless
+a recording tracer is installed) name what the host does inside a tick:
+``engine_tick`` holds ``admit`` (``prefill`` dispatch, ``first_token_wait``
+on the first token's logits, ``insert`` dispatch), ``decode`` (staging and
+dispatch of the pool step), ``token_wait`` (reading the step's tokens
+back) and ``emit`` (per-slot bookkeeping, metrics callbacks, retirement).
+``submit`` records a ``submit`` event with the request's ``rid``, which
+its ``admit`` span carries too.
 """
 from __future__ import annotations
 
@@ -76,6 +85,7 @@ class ContinuousBatcher:
         self.metrics = metrics if metrics is not None else ServeMetrics()
         self.eos_id = eos_id
         self.tick_s = float(tick_s)
+        self._track = f"engine:{self.cfg.name}"
         self.energy_model = None
         if envelope is not None:
             from repro.power import EnergyModel
@@ -141,6 +151,8 @@ class ContinuousBatcher:
                 f"request {req.rid} wants arch {req.arch!r}, engine serves "
                 f"{self.cfg.name!r} (route first: repro.serve.router)")
         self.metrics.on_submit(req.rid, req.arrival_s, arch=req.arch)
+        get_tracer().event("submit", cat="engine", track=self._track,
+                           rid=req.rid)
         self._pending.append(req)
         self._pending.sort(key=lambda r: (r.arrival_s, r.rid))
 
@@ -169,10 +181,14 @@ class ContinuousBatcher:
         batch = {"tokens": jnp.asarray(toks)}
         for k, v in req.extras.items():
             batch[k] = v
-        logits, cache = self._prefill_fn(req.prompt_len)(self.params, batch)
-        first = int(np.asarray(logits).argmax(axis=-1)[0])
-
-        self._pool = self._insert(self._pool, cache, slot)
+        tracer, track = get_tracer(), self._track
+        with tracer.span("prefill", cat="engine", track=track):
+            logits, cache = self._prefill_fn(req.prompt_len)(self.params,
+                                                             batch)
+        with tracer.span("first_token_wait", cat="engine", track=track):
+            first = int(np.asarray(logits).argmax(axis=-1)[0])
+        with tracer.span("insert", cat="engine", track=track):
+            self._pool = self._insert(self._pool, cache, slot)
         self._active[slot] = True
         self._pos[slot] = req.prompt_len
         self._last_tok[slot] = first
@@ -200,6 +216,15 @@ class ContinuousBatcher:
         active slot one decode step, retire finished requests.  Returns
         True while any work remains (live slots, queue, or future
         arrivals)."""
+        tracer, track = get_tracer(), self._track
+        with tracer.span("engine_tick", cat="engine", track=track,
+                         tick=self._ticks) as tick_span:
+            admitted, live, joules = self._tick(tracer, track)
+            tick_span.set(live=live, queued=len(self._queue),
+                          admitted=admitted, joules=joules)
+        return bool(self._active.any() or self._queue or self._pending)
+
+    def _tick(self, tracer, track):
         import jax.numpy as jnp
 
         now = self.now_s
@@ -209,46 +234,46 @@ class ContinuousBatcher:
 
         # one interleaved prefill per tick: admissions must not starve the
         # decode cadence of the requests already running
+        admitted = 0
         if self._queue and self.free_slots:
             slot = int(np.flatnonzero(~self._active)[0])
-            self._admit(self._queue.pop(0), slot, t_end)
+            req = self._queue.pop(0)
+            with tracer.span("admit", cat="engine", track=track,
+                             rid=req.rid, prompt_len=req.prompt_len,
+                             slot=slot):
+                self._admit(req, slot, t_end)
+            admitted = 1
 
         live_before = [r.rid for r in self._slot_req if r is not None]
         if self._active.any():
-            toks = jnp.asarray(
-                self._last_tok.reshape(self.n_slots, 1, 1))
-            poss = jnp.asarray(self._pos)
-            nxt, self._pool = self._step(self.params, self._pool, toks,
-                                         poss)
-            nxt = np.asarray(nxt).reshape(self.n_slots)
-            for slot in np.flatnonzero(self._active):
-                req = self._slot_req[slot]
-                tok = int(nxt[slot])
-                self._out[req.rid].append(tok)
-                self._last_tok[slot] = tok
-                self._pos[slot] += 1
-                self._remaining[slot] -= 1
-                self.metrics.on_token(req.rid, t_end)
-                if self._remaining[slot] <= 0 or \
-                        (self.eos_id is not None and tok == self.eos_id):
-                    self._retire(slot, t_end)
+            with tracer.span("decode", cat="engine", track=track):
+                toks = jnp.asarray(
+                    self._last_tok.reshape(self.n_slots, 1, 1))
+                poss = jnp.asarray(self._pos)
+                nxt, self._pool = self._step(self.params, self._pool, toks,
+                                             poss)
+            with tracer.span("token_wait", cat="engine", track=track):
+                nxt = np.asarray(nxt).reshape(self.n_slots)
+            with tracer.span("emit", cat="engine", track=track):
+                for slot in np.flatnonzero(self._active):
+                    req = self._slot_req[slot]
+                    tok = int(nxt[slot])
+                    self._out[req.rid].append(tok)
+                    self._last_tok[slot] = tok
+                    self._pos[slot] += 1
+                    self._remaining[slot] -= 1
+                    self.metrics.on_token(req.rid, t_end)
+                    if self._remaining[slot] <= 0 or \
+                            (self.eos_id is not None and tok == self.eos_id):
+                        self._retire(slot, t_end)
 
         self._ticks += 1
+        joules = 0.0
         if self.energy_model is not None:
             joules = self.energy_model.tick_joules(
                 self.tick_s, len(live_before) / self.n_slots)
-            self.metrics.charge_tick(joules, live_before)
-        else:
-            joules = 0.0
-            self.metrics.charge_tick(0.0, live_before)
-        # one complete-span per tick on the virtual clock (no-op unless a
-        # tracer is enabled): the engine's swim-lane in a Perfetto trace
-        get_tracer().complete_span(
-            "tick", now, t_end, cat="engine",
-            track=f"engine:{self.cfg.name}", tick=self._ticks - 1,
-            live=len(live_before), queued=len(self._queue),
-            joules=joules)
-        return bool(self._active.any() or self._queue or self._pending)
+        self.metrics.charge_tick(joules, live_before)
+        return admitted, len(live_before), joules
 
     # ---------------------------------------------------------------- run
     def run(self, requests: Optional[List[Request]] = None,

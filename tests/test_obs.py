@@ -1,6 +1,7 @@
-"""repro.obs: tracer null-object contract, span nesting, deterministic
-exporters, the metrics registry's consolidated snapshot, the post-mortem
-report sections, and the ceil-based nearest-rank percentile fix.
+"""repro.obs: tracer null-object contract, span nesting, the profiler-clock
+bridge (annotations and compile events), deterministic exporters, the
+post-mortem report sections, and the ceil-based nearest-rank percentile
+fix.
 
 The tier-1 pins here are behavioral, not cosmetic: the ambient tracer
 must default to a no-op (instrumented call sites run in every existing
@@ -13,9 +14,9 @@ import json
 import pytest
 
 from repro import obs
-from repro.obs import (NULL_SPAN, NULL_TRACER, MetricsRegistry, Tracer,
-                       chrome_trace, get_tracer, jsonl_line, set_tracer,
-                       text_summary, use_tracer)
+from repro.obs import (NULL_SPAN, NULL_TRACER, Tracer, chrome_trace,
+                       get_tracer, jsonl_line, set_tracer, text_summary,
+                       use_tracer)
 from repro.obs.report import render
 
 
@@ -127,6 +128,115 @@ def test_attrs_are_clamped_to_json():
     assert isinstance(attrs["weird"], str)
 
 
+# ------------------------------------------------------ the profiler's clock
+class Annotations:
+    """An ``annotate`` callable that logs each enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Annotation:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+        return Annotation()
+
+
+def _nested(tr):
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+
+
+def _out_of_order(tr):
+    outer = tr.span("outer")
+    inner = tr.span("inner")
+    outer.finish()                           # before its child
+    inner.finish()
+    inner.finish()                           # a second finish is a no-op
+
+
+def _raises(tr):
+    with pytest.raises(KeyError):
+        with tr.span("outer"):
+            with tr.span("inner"):
+                raise KeyError("gene")
+
+
+@pytest.mark.parametrize("drive,exits", [
+    (_nested, ["inner", "outer"]),
+    (_out_of_order, ["outer", "inner"]),
+    (_raises, ["inner", "outer"]),
+])
+def test_annotate_enters_and_exits_once_per_span(drive, exits):
+    ann = Annotations()
+    tr = Tracer(clock=lambda: 0.0, annotate=ann)
+    drive(tr)
+    assert [n for e, n in ann.log if e == "enter"] == ["outer", "inner"]
+    assert [n for e, n in ann.log if e == "exit"] == exits
+    assert sorted(r["name"] for r in tr.records) == ["inner", "outer"]
+
+
+def test_events_carry_their_parent_span():
+    tr = Tracer(clock=lambda: 0.0)
+    top = tr.event("before")
+    with tr.span("outer") as outer:
+        with tr.span("inner") as inner:
+            deep = tr.event("deep")
+        shallow = tr.event("shallow")
+    assert top["parent"] is None
+    assert deep["parent"] == inner.id
+    assert shallow["parent"] == outer.id
+
+
+def test_compile_listener_records_stages_under_the_open_span():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import profiler_tracer
+
+    tr = profiler_tracer()
+    with use_tracer(tr):
+        with tr.span("measure") as sp:
+            # a function of its own: nothing cached can stand in for it
+            jax.block_until_ready(jax.jit(lambda x: x * 3.0 + 7.0)(
+                jnp.arange(5.0)))
+    compiles = [r for r in tr.records
+                if r["type"] == "event" and r["name"] == "compile"]
+    assert {c["attrs"]["stage"] for c in compiles} >= {"trace", "lower",
+                                                       "compile"}
+    assert all(c["parent"] == sp.id and c["attrs"]["seconds"] >= 0
+               for c in compiles)
+    # the null tracer takes the listener's events and records nothing
+    jax.block_until_ready(jax.jit(lambda x: x - 1.0)(jnp.arange(3.0)))
+    assert len(tr.records) == len(compiles) + 1
+
+
+def test_profiler_tracer_spans_land_on_the_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from repro.obs import profiler_tracer
+
+    tr = profiler_tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs_outer"):
+            with tr.span("obs_inner"):
+                jax.block_until_ready(jax.numpy.ones(4) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    names = [e.name for plane in jax.profiler.ProfileData.from_file(
+        path).planes if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events]
+    assert names.count("obs_outer") == 1 and names.count("obs_inner") == 1
+
+
 # ---------------------------------------------------------------- exporters
 def make_records():
     tr = Tracer(clock=lambda: 0.0)
@@ -176,59 +286,6 @@ def test_text_summary_counts_spans_and_events():
     s = text_summary(make_records())
     assert "2 spans, 1 events" in s
     assert "plan/verify" in s and "loop/tick" in s
-
-
-# ---------------------------------------------------------- metrics registry
-def test_registry_instruments_are_get_or_create():
-    reg = MetricsRegistry()
-    assert reg.counter("a") is reg.counter("a")
-    assert reg.gauge("g") is reg.gauge("g")
-    assert reg.histogram("h") is reg.histogram("h")
-    reg.counter("a").inc(2)
-    reg.gauge("g").set(7.5)
-    for v in (1.0, 2.0, 3.0, 4.0):
-        reg.histogram("h").observe(v)
-    snap = reg.snapshot()
-    assert snap["counters"]["a"] == 2.0
-    assert snap["gauges"]["g"] == 7.5
-    h = snap["histograms"]["h"]
-    assert h["count"] == 4 and h["mean"] == 2.5
-    assert h["min"] == 1.0 and h["max"] == 4.0
-    assert h["p50"] == 2.0                   # ceil nearest-rank
-    with pytest.raises(ValueError):
-        reg.counter("a").inc(-1)
-
-
-def test_registry_consolidates_existing_faces():
-    from repro.core.search_cache import SearchCache
-    from repro.serve.health import EndpointHealth, HealthConfig
-    from repro.serve.metrics import ServeMetrics
-
-    reg = MetricsRegistry()
-    cache = SearchCache()
-    cache.stats.candidates = 3
-    reg.attach_cache_stats("search", cache.stats)
-    reg.attach_serve_metrics("serve", ServeMetrics())
-    h = EndpointHealth("ep0", HealthConfig(error_threshold=1))
-    h.observe_error("died")
-    reg.attach_health("health", {"ep0": h})
-    snap = reg.snapshot()["collected"]
-    assert snap["search"]["candidates"] == 3
-    assert snap["serve"]["completed"] == 0
-    assert snap["health"]["ep0"]["state"] == "quarantined"
-    assert snap["health"]["ep0"]["transitions"] == 1
-    # and the public faces are untouched
-    assert cache.stats.to_dict()["candidates"] == 3
-    assert h.transitions[0]["observed"]["consecutive_errors"] == 1
-
-
-def test_registry_dead_collector_cannot_sink_snapshot():
-    reg = MetricsRegistry()
-    reg.register_collector("ok", lambda: 1)
-    reg.register_collector("dead", lambda: 1 / 0)
-    snap = reg.snapshot()["collected"]
-    assert snap["ok"] == 1
-    assert "ZeroDivisionError" in snap["dead"]["error"]
 
 
 # ------------------------------------------------------------------- report

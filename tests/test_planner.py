@@ -108,3 +108,58 @@ def test_candidate_compile_error_stays_on_record():
     assert report.selected is not None and not report.selected.error
     assert all(not r.error for r in report.records
                if r.paper_analogue != "FPGA")
+
+
+def test_plan_spans_cover_the_reference_and_split_each_measurement():
+    """``offload`` spans the whole call, the reference measurement
+    included; each ``measure`` splits into ``first_call``, ``repeats`` and
+    (for a candidate) ``compare``; a candidate that fails to build stops
+    in ``first_call``."""
+    import jax.numpy as jnp
+
+    from repro.core.offloadable import LoopNest, OffloadableApp
+    from repro.obs import Tracer, use_tracer
+
+    def broken(state):
+        raise ValueError("kernel refused by the compiler")
+
+    def double(state):
+        return dict(state, out=state["x"] * 2.0)
+
+    app = OffloadableApp(
+        name="spans",
+        nests=[LoopNest("scale", {"seq": double, "dp": double,
+                                  "pallas": broken})],
+        make_inputs=lambda seed=0, small=False: {
+            "x": jnp.arange(8 if small else 64, dtype=jnp.float32)})
+    tr = Tracer()
+    with use_tracer(tr):
+        report = plan_offload(app, UserTarget(),
+                              runner=TimedRunner(repeats=2),
+                              ga_cfg=GAConfig(population=2, generations=2,
+                                              seed=0))
+    spans = {r["id"]: r for r in tr.records if r["type"] == "span"}
+    kids = {}
+    for r in sorted(spans.values(), key=lambda r: r["id"]):
+        kids.setdefault(r["parent"], []).append(r["name"])
+    offload, = [s for s in spans.values() if s["name"] == "offload"]
+    assert offload["parent"] is None
+    assert offload["attrs"]["ref_time_s"] == report.ref_time_s
+    assert offload["attrs"]["n_verifications"] == len(report.records)
+    measures = [s for s in spans.values() if s["name"] == "measure"]
+    ref, = [m for m in measures if m["attrs"]["reference"]]
+    assert ref["parent"] == offload["id"]
+    assert offload["t0"] <= ref["t0"] and ref["t1"] <= offload["t1"]
+    assert kids[ref["id"]] == ["first_call", "repeats"]
+    seen = set()
+    for m in measures:
+        assert m["t0"] >= offload["t0"] and m["t1"] <= offload["t1"]
+        if m is ref:
+            continue
+        if m["attrs"]["correct"]:
+            assert kids[m["id"]] == ["first_call", "repeats", "compare"]
+            seen.add("ok")
+        else:
+            assert kids[m["id"]] == ["first_call"]
+            seen.add("broken")
+    assert seen == {"ok", "broken"}

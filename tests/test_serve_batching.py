@@ -183,3 +183,51 @@ def test_generate_reference_does_not_retrace_across_calls():
     generate(model, params, batch, prompt_len=8, gen=5, cache_len=32)
     assert (prefill, step) == _jits_for(model, 32)
     assert prefill._cache_size() + step._cache_size() == n0
+
+
+def test_engine_spans_name_each_step_of_a_tick():
+    """With a recording tracer each tick is an ``engine_tick`` span over
+    ``admit`` (prefill, first-token wait, insert), ``decode``,
+    ``token_wait`` and ``emit``; each request's ``submit`` event and its
+    ``admit`` span share its rid; and tracing changes no token."""
+    from repro.obs import Tracer, use_tracer
+    cfg, model, params = make_model()
+    toks = prompts(cfg, 3, 8)
+
+    def serve(tracer):
+        engine = ContinuousBatcher(model, params, n_slots=2, cache_len=32)
+        reqs = [Request(rid=f"r{i}", arch=cfg.name, prompt_len=8,
+                        max_gen=4, tokens=toks[i],
+                        arrival_s=i * engine.tick_s) for i in range(3)]
+        with use_tracer(tracer):
+            return engine.run(reqs)
+
+    tr = Tracer()
+    traced, plain = serve(tr), serve(None)
+    assert traced.keys() == plain.keys()
+    assert all(np.array_equal(traced[k], plain[k]) for k in plain)
+
+    spans = [r for r in tr.records if r["type"] == "span"]
+    kids = {}
+    for r in sorted(spans, key=lambda r: r["id"]):
+        kids.setdefault(r["parent"], []).append(r)
+    ticks = [r for r in spans if r["name"] == "engine_tick"]
+    assert [t["attrs"]["tick"] for t in sorted(ticks, key=lambda r: r["id"])
+            ] == list(range(len(ticks)))
+    assert sum(t["attrs"]["admitted"] for t in ticks) == 3
+    assert all(t["parent"] is None for t in ticks)
+    for t in ticks:
+        names = [c["name"] for c in kids.get(t["id"], [])]
+        steps = ["admit"] * t["attrs"]["admitted"]
+        if t["attrs"]["live"]:
+            steps += ["decode", "token_wait", "emit"]
+        assert names == steps
+    admits = [r for r in spans if r["name"] == "admit"]
+    for a in admits:
+        assert [c["name"] for c in kids[a["id"]]] == [
+            "prefill", "first_token_wait", "insert"]
+    submits = {e["attrs"]["rid"]: e for e in tr.records
+               if e["type"] == "event" and e["name"] == "submit"}
+    assert sorted(a["attrs"]["rid"] for a in admits) == sorted(submits) \
+        == ["r0", "r1", "r2"]
+    assert all(a["t0"] >= submits[a["attrs"]["rid"]]["t"] for a in admits)
