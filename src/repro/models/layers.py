@@ -305,9 +305,16 @@ def quantize_kv(x):
     return q, scale.astype(jnp.float32)
 
 
+def _valid_keys(n_keys, cache_len):
+    """Mask of the stored keys below ``cache_len`` (a scalar, or one per
+    row [B]), broadcastable against scores [B,G,R,Q,S]."""
+    return jnp.arange(n_keys) < jnp.reshape(cache_len, (-1, 1, 1, 1, 1))
+
+
 def decode_attention_quant(q, k_q, k_scale, v_q, v_scale, cache_len, *,
                            softcap: float = 0.0):
-    """q [B,1,H,Dh]; k_q/v_q int8 [B,S,KV,Dh]; scales [B,S,KV,1]."""
+    """q [B,1,H,Dh]; k_q/v_q int8 [B,S,KV,Dh]; scales [B,S,KV,1];
+    cache_len a scalar or one per row [B]."""
     kvh = k_q.shape[2]
     qg = _group_q(q, kvh)
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -319,9 +326,7 @@ def decode_attention_quant(q, k_q, k_scale, v_q, v_scale, cache_len, *,
     scores = scores * scale
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
-    kpos = jnp.arange(k_q.shape[1])
-    valid = kpos < cache_len
-    scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
+    scores = jnp.where(_valid_keys(k_q.shape[1], cache_len), scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     vs = v_scale[..., 0]                                # [B,S,KV]
     probs = probs * jnp.transpose(vs, (0, 2, 1))[:, :, None, None, :]
@@ -336,7 +341,8 @@ def decode_attention_quant(q, k_q, k_scale, v_q, v_scale, cache_len, *,
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
                      softcap: float = 0.0):
-    """q [B,1,H,Dh]; caches [B,S,KV,Dh]; cache_len = #valid entries.
+    """q [B,1,H,Dh]; caches [B,S,KV,Dh]; cache_len = #valid entries, a
+    scalar or one per row [B].
 
     For ring-buffer (windowed) caches every stored entry is valid once the
     ring wraps; validity is simply ``kpos < cache_len`` with cache_len capped
@@ -349,9 +355,8 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
     scores = scores * scale
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
-    kpos = jnp.arange(k_cache.shape[1])
-    valid = kpos < cache_len
-    scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
+    scores = jnp.where(_valid_keys(k_cache.shape[1], cache_len), scores,
+                       NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache)
     return out.reshape(q.shape)

@@ -294,34 +294,51 @@ def _apply_dense_block(p, cfg, plan, rules, h, positions, window,
     return h, aux, kv
 
 
-def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window):
-    """h [B,1,D]; cache {k,v: [B,W,KV,Dh]}; pos = absolute position scalar."""
+def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window,
+                        layer=None):
+    """h [B,1,D]; pos the absolute position: a scalar, or int32[B], one per
+    row.
+
+    Without ``layer`` the cache is this layer's {k,v: [B,W,KV,Dh]} and comes
+    back with the new K/V written at the slot of ``pos``.  With ``layer``
+    it is the whole stack {k,v: [L,B,W,KV,Dh]}: each row's new K/V is
+    written at (layer, row, the row's slot) and the stack comes back, so
+    a layer loop that carries it updates it in place.
+    """
     x = layers.apply_norm(p["attn_norm"], h, cfg.norm)
     q = layers.q_project(p["attn"], cfg, x)
     k, v = layers.kv_project(p["attn"], cfg, x)
-    posv = jnp.full((h.shape[0], 1), pos, jnp.int32)
+    posv = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (h.shape[0], 1))
     q = layers.apply_rope(q, posv, cfg.rope_theta)
     k = layers.apply_rope(k, posv, cfg.rope_theta)
-    w = cache["k"].shape[1]
+    w = cache["k"].shape[-3]
     slot = (pos % w) if window else jnp.minimum(pos, w - 1)
-    quant = "k_scale" in cache
-    if quant:
-        k, k_s = layers.quantize_kv(k)
-        v, v_s = layers.quantize_kv(v)
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
-    k_cache = rules.constrain(k_cache, ("batch", "kv_seq", "kv_heads", None))
-    v_cache = rules.constrain(v_cache, ("batch", "kv_seq", "kv_heads", None))
+    new = {"k": k, "v": v}
+    if "k_scale" in cache:
+        new["k"], new["k_scale"] = layers.quantize_kv(k)
+        new["v"], new["v_scale"] = layers.quantize_kv(v)
+    if layer is None:
+        cache = {n: jax.lax.dynamic_update_slice(
+            c, new[n].astype(c.dtype), (0, slot, 0, 0))
+            for n, c in cache.items()}
+        lc = cache
+    else:
+        # one update per row: a scatter would pin the stack to a layout
+        # with the row's (KV, Dh) minor and relayout it at the jit boundary
+        def put(c, x):
+            for b in range(h.shape[0]):
+                c = jax.lax.dynamic_update_slice(
+                    c, x[None, b:b + 1].astype(c.dtype),
+                    (layer, b, slot[b], 0, 0))
+            return c
+        cache = {n: put(c, new[n]) for n, c in cache.items()}
+        lc = {n: c[layer] for n, c in cache.items()}
+    k_cache = rules.constrain(lc["k"], ("batch", "kv_seq", "kv_heads", None))
+    v_cache = rules.constrain(lc["v"], ("batch", "kv_seq", "kv_heads", None))
     cache_len = jnp.minimum(pos + 1, w)
-    if quant:
-        ks_cache = jax.lax.dynamic_update_slice(
-            cache["k_scale"], k_s, (0, slot, 0, 0))
-        vs_cache = jax.lax.dynamic_update_slice(
-            cache["v_scale"], v_s, (0, slot, 0, 0))
+    if "k_scale" in cache:
         attn_out = layers.decode_attention_quant(
-            q, k_cache, ks_cache, v_cache, vs_cache, cache_len,
+            q, k_cache, lc["k_scale"], v_cache, lc["v_scale"], cache_len,
             softcap=cfg.logit_softcap)
     else:
         attn_out = layers.decode_attention(q, k_cache, v_cache, cache_len,
@@ -338,11 +355,9 @@ def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window):
                                      groups=plan.moe_groups)
     else:
         y = layers.apply_ffn(p["ffn"], x, cfg.ffn_act, cfg.use_bias)
-    new_cache = {"k": k_cache, "v": v_cache}
-    if quant:
-        new_cache["k_scale"] = ks_cache
-        new_cache["v_scale"] = vs_cache
-    return h + y, new_cache
+    if layer is None:
+        cache = dict(cache, k=k_cache, v=v_cache)
+    return h + y, cache
 
 
 def _apply_cross_block(p, cfg, plan, rules, h, ctx, collect=False):
@@ -772,12 +787,32 @@ def init_cache_with_context(cfg, plan, rules, params, batch, batch_size,
 # ===========================================================================
 
 def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
-    """One decode step. tokens [B,1] int32; pos scalar absolute position."""
+    """One decode step. tokens [B,1] int32; pos the absolute position, a
+    scalar for the whole batch or int32[B], one per row (dense and moe:
+    each row attends to and writes at its own position)."""
     h = _embed(cfg, rules, params, tokens)
     fam = cfg.family
     window = _window_of(cfg)
 
-    if fam in ("dense", "moe"):
+    if jnp.ndim(pos) and fam not in ("dense", "moe"):
+        raise ValueError(f"a position per row needs a plain KV stack "
+                         f"(dense or moe), not family {fam!r}")
+
+    if fam in ("dense", "moe") and jnp.ndim(pos):
+        # the stack rides in the carry, not in xs/ys: each layer writes its
+        # B new rows into it and reads its own layer back, so XLA updates
+        # the buffer in place instead of restacking it
+        def body(carry, xs):
+            hh, kv = carry
+            lp, i = xs
+            return _decode_dense_block(lp, cfg, plan, rules, hh, kv, pos,
+                                       window, layer=i), None
+
+        (h, new_attn), _ = jax.lax.scan(
+            body, (h, cache["attn"]),
+            (params["blocks"], jnp.arange(cfg.n_layers)))
+        new_cache = {"attn": new_attn}
+    elif fam in ("dense", "moe"):
         def body(hh, xs):
             lp, lc = xs
             return _decode_dense_block(lp, cfg, plan, rules, hh, lc, pos,

@@ -1,18 +1,30 @@
 """Continuous batching: slot-based decode over a fixed-shape pool.
 
-The engine holds ``n_slots`` per-request decode caches stacked on a new
-leading slot axis and advances them with **one** jitted
-``vmap(decode_step)`` — requests join and leave at decode-step granularity
-without ever changing the traced shapes, so the step compiles exactly once
-per engine (pinned by ``ContinuousBatcher.traces`` and
-tests/test_serve_batching.py).
+The engine holds ``n_slots`` request slots in one decode cache and
+advances them with **one** jitted step over the whole pool — requests join
+and leave at decode-step granularity without ever changing the traced
+shapes, so the step compiles exactly once per engine (pinned by
+``ContinuousBatcher.traces`` and tests/test_serve_batching.py).
+
+The pool takes one of two forms, chosen once from the model's layer type
+(``ContinuousBatcher.pool_path``):
+
+  * ``"inplace"`` — a dense model with a plain KV stack: the pool is
+    ``init_cache(cfg, n_slots, cache_len)``, its batch axis the slot axis
+    (``[L, S, W, KV, Dh]``), and the step is ``Model.decode_step`` with a
+    position per slot, which writes one K/V row per slot and layer;
+  * ``"vmap"`` — recurrent (ssm/hybrid), MoE, vlm and audio models: one
+    batch-1 cache per slot stacked on a new leading axis, stepped by
+    ``vmap(decode_step)``.
+
+Both jitted programs take the pool donated and hand it back updated.
 
 Slot-pool invariants (the ROADMAP contract):
 
-  * the pool's leading axis is ``n_slots`` on every cache leaf; a slot's
-    cache is replaced wholesale at admission (jitted
-    ``dynamic_update_index_in_dim`` insert, traced index — one trace total),
-    so stale state from a previous occupant can never leak;
+  * a slot's cache is replaced wholesale at admission (jitted
+    ``dynamic_update_slice`` insert at the slot's index on the slot axis,
+    traced index — one trace total), so stale state from a previous
+    occupant can never leak;
   * inactive slots still run the decode step (fixed shapes beat masked
     compute at this scale); their outputs are discarded host-side and their
     cache garbage is overwritten by the next insert;
@@ -96,29 +108,48 @@ class ContinuousBatcher:
         # half of the zero-recompile guarantee
         self.traces = {"decode_step": 0, "insert": 0, "prefill": 0}
 
-        one = init_cache(self.cfg, 1, self.cache_len,
-                         quant=model.plan.kv_cache_quant)
-        self._pool = jax.tree.map(
-            lambda x: jnp.zeros((self.n_slots,) + x.shape, x.dtype), one)
+        # a plain KV stack batches its slots: the pool is one decode cache
+        # whose batch axis is the slot, stepped with a position per slot
+        # and updated in place.  Recurrent state has no row to write, and
+        # batching a MoE model's slots changes what its capacity drops:
+        # those keep one batch-1 cache per slot under vmap.
+        inplace = self.cfg.family == "dense" and self.cfg.moe is None
+        self.pool_path = "inplace" if inplace else "vmap"
+        quant = model.plan.kv_cache_quant
+        if inplace:
+            self._pool = init_cache(self.cfg, self.n_slots, self.cache_len,
+                                    quant=quant)
+        else:
+            one = init_cache(self.cfg, 1, self.cache_len, quant=quant)
+            self._pool = jax.tree.map(
+                lambda x: jnp.zeros((self.n_slots,) + x.shape, x.dtype), one)
 
         def one_step(params, cache, tok, pos):
             logits, new_cache = model.decode_step(params, cache, tok, pos)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [1]
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B]
             return nxt, new_cache
 
         def pool_step(params, pool, toks, poss):
             self.traces["decode_step"] += 1
+            if inplace:
+                return one_step(params, pool, toks[:, 0], poss)
             return jax.vmap(one_step, in_axes=(None, 0, 0, 0))(
                 params, pool, toks, poss)
 
         def pool_insert(pool, one_cache, idx):
             self.traces["insert"] += 1
-            return jax.tree.map(
-                lambda p, o: jax.lax.dynamic_update_index_in_dim(
-                    p, o.astype(p.dtype), idx, 0), pool, one_cache)
+            if inplace:     # the slot is the cache's batch axis
+                def put(p, o):
+                    return jax.lax.dynamic_update_slice_in_dim(p, o, idx, 1)
+            else:           # the slot is a new leading axis
+                def put(p, o):
+                    return jax.lax.dynamic_update_index_in_dim(p, o, idx, 0)
+            return jax.tree.map(lambda p, o: put(p, o.astype(p.dtype)),
+                                pool, one_cache)
 
-        self._step = jax.jit(pool_step)
-        self._insert = jax.jit(pool_insert)
+        # the pool is donated to both: each returns it updated in place
+        self._step = jax.jit(pool_step, donate_argnums=1)
+        self._insert = jax.jit(pool_insert, donate_argnums=0)
         self._prefill_jits: Dict[int, object] = {}
 
         # host-side slot state (numpy: mutated at tick granularity)
@@ -246,7 +277,8 @@ class ContinuousBatcher:
 
         live_before = [r.rid for r in self._slot_req if r is not None]
         if self._active.any():
-            with tracer.span("decode", cat="engine", track=track):
+            with tracer.span("decode", cat="engine", track=track,
+                             path=self.pool_path):
                 toks = jnp.asarray(
                     self._last_tok.reshape(self.n_slots, 1, 1))
                 poss = jnp.asarray(self._pos)

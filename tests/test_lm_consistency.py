@@ -64,6 +64,49 @@ def test_prefill_matches_incremental_decode(arch):
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("arch,cache_len,quant", [
+    ("granite-3-2b", 16, False),         # linear slot min(pos, w - 1)
+    ("h2o-danube-1.8b", 8, False),       # ring slot pos % w
+    ("granite-3-2b", 16, True),          # int8 cache with scales
+])
+def test_vector_pos_matches_scalar_pos_row_by_row(arch, cache_len, quant):
+    """decode_step with a position per row gives each row the logits and
+    the cache row that the scalar path gives that row alone."""
+    from repro.dist.plan import Plan
+    cfg = ARCHS[arch].reduced()
+    model = Model(cfg, Plan(kv_cache_quant=quant))
+    params = model.init(jax.random.PRNGKey(0))
+    b, s = 3, 12
+    batch = _batch(cfg, b, s, jax.random.PRNGKey(5))
+    _, cache = jax.jit(lambda p, bt: model.prefill(p, bt, cache_len))(
+        params, batch)
+    toks = batch["tokens"][:, :1]
+    pos = jnp.asarray([s, s - 3, s + 2], jnp.int32)
+    step = jax.jit(model.decode_step)
+    logits, new = step(params, cache, toks, pos)
+    for r in range(b):
+        row = jax.tree.map(lambda c: c[:, r:r + 1], cache)
+        want_l, want_c = step(params, row, toks[r:r + 1], pos[r])
+        np.testing.assert_allclose(np.asarray(logits[r]),
+                                   np.asarray(want_l[0]), rtol=1e-5,
+                                   atol=1e-5)
+        for got, want in zip(jax.tree.leaves(new), jax.tree.leaves(want_c)):
+            np.testing.assert_allclose(
+                np.asarray(got[:, r], np.float32),
+                np.asarray(want[:, 0], np.float32), rtol=1e-5, atol=1e-5)
+
+
+def test_vector_pos_needs_a_plain_kv_stack():
+    cfg = ARCHS["mamba2-1.3b"].reduced()
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    _, cache = model.prefill(params, _batch(cfg, 2, 4, jax.random.PRNGKey(1)),
+                             8)
+    with pytest.raises(ValueError, match="plain KV stack"):
+        model.decode_step(params, cache, jnp.zeros((2, 1), jnp.int32),
+                          jnp.asarray([4, 4], jnp.int32))
+
+
 def test_chunked_loss_matches_full_loss():
     """Property: chunked-vocab xent == full-logit xent."""
     import dataclasses

@@ -18,9 +18,9 @@ from repro.serve import ContinuousBatcher, Request
 ARCH = "granite-3-2b"
 
 
-def make_model(arch=ARCH, seed=0):
+def make_model(arch=ARCH, seed=0, plan=None):
     cfg = get_config(arch).reduced()
-    model = Model(cfg)
+    model = Model(cfg, plan)
     params = model.init(jax.random.PRNGKey(seed))
     return cfg, model, params
 
@@ -41,13 +41,20 @@ def sequential_reference(model, params, toks, prompt_len, gen, cache_len):
     return out
 
 
-def test_parity_with_midflight_joins_and_early_finishes():
+@pytest.mark.parametrize("arch,cache_len,quant", [
+    (ARCH, 32, False),
+    ("h2o-danube-1.8b", 12, False),      # window ring: slot pos % 12 wraps
+    (ARCH, 32, True),                    # kv_cache_quant: int8 + scales
+], ids=["dense", "windowed", "int8kv"])
+def test_parity_with_midflight_joins_and_early_finishes(arch, cache_len,
+                                                        quant):
     """The satellite pin: staggered arrivals (requests join while others
     decode), heterogeneous max_gen (early finishers free slots mid-run),
     and more requests than slots (slot recycling) — token-identical to the
-    sequential reference throughout."""
-    cfg, model, params = make_model()
-    prompt_len, cache_len = 8, 32
+    sequential reference throughout, on the in-place pool."""
+    from repro.dist.plan import Plan
+    cfg, model, params = make_model(arch, plan=Plan(kv_cache_quant=quant))
+    prompt_len = 8
     gens = [6, 3, 9, 4, 7]                       # early finishes + stragglers
     toks = prompts(cfg, len(gens), prompt_len)
     engine = ContinuousBatcher(model, params, n_slots=2,
@@ -57,6 +64,7 @@ def test_parity_with_midflight_joins_and_early_finishes():
                     arrival_s=i * 1.5 * engine.tick_s)
             for i in range(len(gens))]
     out = engine.run(reqs)
+    assert engine.pool_path == "inplace"
 
     for i, g in enumerate(gens):
         ref = np.asarray(generate(
@@ -82,6 +90,40 @@ def test_parity_holds_for_recurrent_families(arch):
     ref = sequential_reference(model, params, toks, 8, 5, 16)
     for rid in ref:
         assert np.array_equal(out[rid], ref[rid]), rid
+
+
+def _bytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("arch,path", [
+    (ARCH, "inplace"),
+    ("mamba2-1.3b", "vmap"),
+    ("recurrentgemma-2b", "vmap"),
+    ("moonshot-v1-16b-a3b", "vmap"),
+])
+def test_pool_is_donated_and_never_copied(arch, path):
+    """The step and the insert take the pool donated and hand it back in
+    place: the compiled programs alias at least the pool's bytes and
+    allocate no second pool.  Only a plain KV stack takes the in-place
+    pool; recurrent state and MoE keep one cache per slot under vmap."""
+    cfg, model, params = make_model(arch)
+    engine = ContinuousBatcher(model, params, n_slots=4, cache_len=32)
+    assert engine.pool_path == path
+    pool = engine._pool
+    n = _bytes(pool)
+    toks = np.zeros((4, 1, 1), np.int32)
+    poss = np.zeros((4,), np.int32)
+    _, one = model.prefill(params, {"tokens": prompts(cfg, 1, 8)}, 32)
+    for fn, args in ((engine._step, (params, pool, toks, poss)),
+                     (engine._insert, (pool, one, 1))):
+        ma = fn.lower(*args).compile().memory_analysis()
+        assert ma.alias_size_in_bytes >= n
+        assert ma.output_size_in_bytes - ma.alias_size_in_bytes < n
+    if path == "inplace":
+        assert jax.tree.leaves(pool)[0].shape == (cfg.n_layers, 4, 32,
+                                                  cfg.n_kv_heads,
+                                                  cfg.head_dim)
 
 
 def test_decode_step_traces_exactly_once():
@@ -222,6 +264,8 @@ def test_engine_spans_name_each_step_of_a_tick():
         if t["attrs"]["live"]:
             steps += ["decode", "token_wait", "emit"]
         assert names == steps
+    decodes = [r for r in spans if r["name"] == "decode"]
+    assert decodes and all(r["attrs"]["path"] == "inplace" for r in decodes)
     admits = [r for r in spans if r["name"] == "admit"]
     for a in admits:
         assert [c["name"] for c in kids[a["id"]]] == [
