@@ -94,11 +94,15 @@ def serve_kv_bytes(cfg, cache_len: int, *, quant: bool = False) -> int:
         else cache_len
     if cfg.family == "hybrid":
         h = cfg.hybrid
-        n_att = sum(1 for i in range(cfg.n_layers)
-                    if h.pattern[i % len(h.pattern)] != "recurrent")
+        kinds = [h.pattern[i % len(h.pattern)] for i in range(cfg.n_layers)]
         w = h.lru_width or cfg.d_model
-        rec_state = (cfg.n_layers - n_att) * w * 4
-        return n_att * eff * per_tok * el + rec_state
+        rec_state = kinds.count("recurrent") * w * 4
+        if "mamba" in kinds:
+            s = cfg.ssm
+            rec_state += kinds.count("mamba") * s.n_heads(cfg.d_model) \
+                * s.d_state * s.headdim * 4
+        att = {"local_attn": eff, "attention": cache_len}
+        return sum(att.get(k, 0) for k in kinds) * per_tok * el + rec_state
     n_att = cfg.n_layers
     if getattr(cfg, "cross_attn_every", 0):
         # cross-attn caches are context-length-sized, counted separately by

@@ -10,6 +10,7 @@ from repro.configs.base import (ModelConfig, MoEConfig, SSMConfig,
                                 SHAPES)
 
 from repro.configs.granite_3_2b import CONFIG as _granite
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite4h
 from repro.configs.h2o_danube_1_8b import CONFIG as _danube
 from repro.configs.command_r_plus_104b import CONFIG as _command_r
 from repro.configs.nemotron_4_15b import CONFIG as _nemotron
@@ -23,7 +24,7 @@ from repro.configs.seamless_m4t_medium import CONFIG as _seamless
 ARCHS: dict[str, ModelConfig] = {
     c.name: c for c in [
         _granite, _danube, _command_r, _nemotron, _moonshot,
-        _arctic, _rgemma, _mamba2, _llama_vision, _seamless,
+        _arctic, _rgemma, _mamba2, _llama_vision, _seamless, _granite4h,
     ]
 }
 

@@ -35,6 +35,7 @@ class SSMConfig:
     n_groups: int = 1
     conv_kernel: int = 4
     chunk: int = 256               # SSD chunk length (MXU-friendly)
+    conv_bias: bool = False        # bias on the depthwise causal conv
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -45,7 +46,10 @@ class SSMConfig:
 
 @dataclass(frozen=True)
 class HybridConfig:
-    """RecurrentGemma-style hybrid: pattern of block kinds, repeated."""
+    """A pattern of block kinds, repeated.  Kinds: ``recurrent`` (RG-LRU)
+    and ``local_attn`` (window ``ModelConfig.window``), as RecurrentGemma;
+    ``mamba`` (Mamba-2 mixer, ``ModelConfig.ssm``) and ``attention`` (full
+    causal attention), as Granite 4.0-H.  Every block carries an FFN."""
     pattern: Tuple[str, ...] = ("recurrent", "recurrent", "local_attn")
     lru_width: int = 0             # 0 => d_model
     conv_kernel: int = 4
@@ -81,6 +85,16 @@ class ModelConfig:
     # enc-dec (audio): encoder depth; n_layers is the decoder depth.
     encoder_layers: int = 0
     n_frames: int = 3072           # stub audio frontend output length
+    # Granite multipliers and the norm's eps; the defaults are the plain
+    # model: embeddings times sqrt(d_model) (``embed_multiplier`` 0),
+    # scores times 1/sqrt(d_head) (``attn_multiplier`` 0), unscaled
+    # residual branches and logits
+    embed_multiplier: float = 0.0
+    attn_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    norm_eps: float = 1e-6
+    pos_embedding: str = "rope"    # rope | nope
     # numerics
     dtype: str = "bfloat16"
     param_dtype: str = "bfloat16"
@@ -100,8 +114,10 @@ class ModelConfig:
     @property
     def is_sub_quadratic(self) -> bool:
         """Can this arch decode at 500k context with O(1)/O(window) state?"""
-        if self.family in ("ssm", "hybrid"):
+        if self.family == "ssm":
             return True
+        if self.family == "hybrid":       # full attention grows with context
+            return "attention" not in self.hybrid.pattern
         return self.attn_kind == "swa" and self.window > 0
 
     @property
@@ -132,12 +148,22 @@ class ModelConfig:
         elif self.family == "hybrid":
             h = self.hybrid
             w = h.lru_width or d
-            rec = d * w * 2 + w * d + w * h.conv_kernel + 4 * w  # proj+gates+conv
-            att = attn_params()
-            n_rec = sum(1 for i in range(self.n_layers)
-                        if h.pattern[i % len(h.pattern)] == "recurrent")
-            n_att = self.n_layers - n_rec
-            layers = n_rec * rec + n_att * att \
+            mixer = {
+                "recurrent": d * w * 2 + w * d + w * h.conv_kernel + 4 * w,
+                "local_attn": attn_params(),
+                "attention": attn_params(),
+            }
+            if self.ssm is not None:
+                s = self.ssm
+                di, nh = s.d_inner(d), s.n_heads(d)
+                conv = di + 2 * s.n_groups * s.d_state
+                # in_proj (z,x,B,C,dt), conv (+bias), out_proj, A,D,dt_bias,
+                # gated-norm scale
+                mixer["mamba"] = (d * (di + conv + nh)
+                                  + conv * (s.conv_kernel + s.conv_bias)
+                                  + di * d + 3 * nh + di)
+            layers = sum(mixer[h.pattern[i % len(h.pattern)]]
+                         for i in range(self.n_layers)) \
                 + self.n_layers * (ffn_params(self.d_ff, gated) + 2 * d)
         else:
             per = attn_params() + 2 * d
@@ -203,6 +229,9 @@ class ModelConfig:
             kw["ssm"] = replace(self.ssm, d_state=16, headdim=32, chunk=16)
         if self.hybrid is not None:
             kw["hybrid"] = replace(self.hybrid, lru_width=128, conv_kernel=4)
+            if "mamba" in self.hybrid.pattern:
+                # the in-place slot pool serves whole periods of the pattern
+                kw["n_layers"] = len(self.hybrid.pattern)
         if self.cross_attn_every:
             kw["cross_attn_every"] = 2
         return replace(self, **kw)

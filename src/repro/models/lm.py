@@ -1,7 +1,8 @@
 """Unified language model covering all assigned architecture families.
 
 families: dense (granite / danube / command-r+ / nemotron), moe (moonshot /
-arctic), hybrid (recurrentgemma), ssm (mamba2), vlm (llama-3.2-vision
+arctic), hybrid (recurrentgemma: RG-LRU + local attention; granite-4.0-h:
+Mamba-2 + NoPE attention), ssm (mamba2), vlm (llama-3.2-vision
 backbone, stub image frontend), audio (seamless enc-dec backbone, stub frame
 frontend).
 
@@ -39,6 +40,31 @@ def _dt(cfg):
 
 def _pdt(cfg):
     return jnp.dtype(cfg.param_dtype)
+
+
+def _norm(cfg, p, x):
+    return layers.apply_norm(p, x, cfg.norm, cfg.norm_eps)
+
+
+def _res(cfg, y):
+    """A residual branch's output as it joins the stream."""
+    m = cfg.residual_multiplier     # in float32: 0.22 has no exact bf16
+    return y if m == 1.0 else (y.astype(jnp.float32) * m).astype(y.dtype)
+
+
+def _rope(cfg, x, positions):
+    if cfg.pos_embedding == "nope":
+        return x
+    return layers.apply_rope(x, positions, cfg.rope_theta)
+
+
+def _scale_q(cfg, q):
+    """Queries scaled so that the attention kernels' 1/sqrt(d_head) gives
+    the model's score multiplier."""
+    m = cfg.attn_multiplier
+    if not m:
+        return q
+    return q * jnp.asarray(m * cfg.head_dim ** 0.5, q.dtype)
 
 
 # ===========================================================================
@@ -122,6 +148,45 @@ def _ssm_block_axes(cfg):
             "ssm": ssm_mod.ssm_axes(cfg)}
 
 
+def _init_mamba_block(key, cfg, dtype):
+    ks = jax.random.split(key, 4)
+    return {
+        "norm": layers.init_norm(ks[0], cfg.d_model, cfg.norm, dtype),
+        "ssm": ssm_mod.init_ssm(ks[1], cfg, dtype),
+        "ffn_norm": layers.init_norm(ks[2], cfg.d_model, cfg.norm, dtype),
+        "ffn": layers.init_ffn(ks[3], cfg.d_model, cfg.d_ff, cfg.ffn_act,
+                               cfg.use_bias, dtype),
+    }
+
+
+def _mamba_block_axes(cfg):
+    return {
+        "norm": layers.norm_axes(cfg.norm),
+        "ssm": ssm_mod.ssm_axes(cfg),
+        "ffn_norm": layers.norm_axes(cfg.norm),
+        "ffn": layers.ffn_axes(cfg.ffn_act, cfg.use_bias),
+    }
+
+
+# a hybrid's block kinds; every other kind is an attention block
+_HYBRID_INIT = {"recurrent": _init_recurrent_block,
+                "mamba": _init_mamba_block}
+_HYBRID_AXES = {"recurrent": _recurrent_block_axes,
+                "mamba": _mamba_block_axes}
+
+
+def _init_hybrid_block(kind, key, cfg, dtype):
+    return _HYBRID_INIT.get(kind, _init_dense_block)(key, cfg, dtype)
+
+
+def _hybrid_block_axes(kind, cfg):
+    return _HYBRID_AXES.get(kind, _dense_block_axes)(cfg)
+
+
+def _hybrid_window(cfg, kind) -> int:
+    return cfg.window if kind == "local_attn" else 0
+
+
 # ===========================================================================
 # whole-model init / axes
 # ===========================================================================
@@ -179,22 +244,15 @@ def init_params(key, cfg: ModelConfig) -> Params:
         groups, tail = hybrid_groups(cfg)
 
         def init_group(k):
-            out = {}
-            for i, kind in enumerate(pat):
-                sub = jax.random.fold_in(k, i)
-                out[f"b{i}"] = (_init_recurrent_block(sub, cfg, dtype)
-                                if kind == "recurrent"
-                                else _init_dense_block(sub, cfg, dtype))
-            return out
+            return {f"b{i}": _init_hybrid_block(
+                kind, jax.random.fold_in(k, i), cfg, dtype)
+                for i, kind in enumerate(pat)}
 
         p["blocks"] = _stack_init(init_group, ks[3], groups)
         if tail:
             p["tail"] = [
-                (_init_recurrent_block(jax.random.fold_in(ks[6], i), cfg,
-                                       dtype)
-                 if pat[i % len(pat)] == "recurrent"
-                 else _init_dense_block(jax.random.fold_in(ks[6], i), cfg,
-                                        dtype))
+                _init_hybrid_block(pat[i % len(pat)],
+                                   jax.random.fold_in(ks[6], i), cfg, dtype)
                 for i in range(tail)]
     elif fam == "ssm":
         p["blocks"] = _stack_init(
@@ -228,15 +286,13 @@ def param_axes(cfg: ModelConfig) -> Params:
                                  "cross": _cross_block_axes(cfg)})
     elif fam == "hybrid":
         pat = cfg.hybrid.pattern
-        group = {f"b{i}": (_recurrent_block_axes(cfg) if k == "recurrent"
-                           else _dense_block_axes(cfg))
+        group = {f"b{i}": _hybrid_block_axes(k, cfg)
                  for i, k in enumerate(pat)}
         p["blocks"] = stack(group)
         _, tail = hybrid_groups(cfg)
         if tail:
-            p["tail"] = [(_recurrent_block_axes(cfg)
-                          if pat[i % len(pat)] == "recurrent"
-                          else _dense_block_axes(cfg)) for i in range(tail)]
+            p["tail"] = [_hybrid_block_axes(pat[i % len(pat)], cfg)
+                         for i in range(tail)]
     elif fam == "ssm":
         p["blocks"] = stack(_ssm_block_axes(cfg))
     return p
@@ -261,24 +317,28 @@ def _window_of(cfg) -> int:
 
 def _embed(cfg, rules, params, tokens):
     h = jnp.take(params["embed"], tokens, axis=0).astype(_dt(cfg))
-    scale = jnp.sqrt(jnp.float32(cfg.d_model)).astype(_dt(cfg))
+    if cfg.embed_multiplier:
+        scale = jnp.asarray(cfg.embed_multiplier, _dt(cfg))
+    else:
+        scale = jnp.sqrt(jnp.float32(cfg.d_model)).astype(_dt(cfg))
     return rules.constrain(h * scale, ("batch", "seq", None))
 
 
 def _apply_dense_block(p, cfg, plan, rules, h, positions, window,
                        collect=False):
-    x = layers.apply_norm(p["attn_norm"], h, cfg.norm)
-    q = layers.q_project(p["attn"], cfg, x)
+    x = _norm(cfg, p["attn_norm"], h)
+    q = _scale_q(cfg, layers.q_project(p["attn"], cfg, x))
     k, v = layers.kv_project(p["attn"], cfg, x)
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(cfg, q, positions)
+    k = _rope(cfg, k, positions)
     q = rules.constrain(q, ("batch", None, "heads", None))
     k = rules.constrain(k, ("batch", None, "kv_heads", None))
     attn_out = layers.attention(q, k, v, causal=True, window=window,
                                 softcap=cfg.logit_softcap, plan=plan)
     h = h + rules.constrain(
-        layers.out_project(p["attn"], cfg, attn_out), ("batch", None, None))
-    x = layers.apply_norm(p["ffn_norm"], h, cfg.norm)
+        _res(cfg, layers.out_project(p["attn"], cfg, attn_out)),
+        ("batch", None, None))
+    x = _norm(cfg, p["ffn_norm"], h)
     if cfg.moe is not None:
         if plan.moe_impl == "shardmap_ep":
             y, aux = moe_mod.apply_moe_ep(p["ffn"], cfg, x, rules,
@@ -289,7 +349,7 @@ def _apply_dense_block(p, cfg, plan, rules, h, positions, window,
                                        groups=plan.moe_groups)
     else:
         y, aux = layers.apply_ffn(p["ffn"], x, cfg.ffn_act, cfg.use_bias), 0.0
-    h = h + rules.constrain(y, ("batch", None, None))
+    h = h + rules.constrain(_res(cfg, y), ("batch", None, None))
     kv = (k, v) if collect else None
     return h, aux, kv
 
@@ -305,12 +365,12 @@ def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window,
     written at (layer, row, the row's slot) and the stack comes back, so
     a layer loop that carries it updates it in place.
     """
-    x = layers.apply_norm(p["attn_norm"], h, cfg.norm)
-    q = layers.q_project(p["attn"], cfg, x)
+    x = _norm(cfg, p["attn_norm"], h)
+    q = _scale_q(cfg, layers.q_project(p["attn"], cfg, x))
     k, v = layers.kv_project(p["attn"], cfg, x)
     posv = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (h.shape[0], 1))
-    q = layers.apply_rope(q, posv, cfg.rope_theta)
-    k = layers.apply_rope(k, posv, cfg.rope_theta)
+    q = _rope(cfg, q, posv)
+    k = _rope(cfg, k, posv)
     w = cache["k"].shape[-3]
     slot = (pos % w) if window else jnp.minimum(pos, w - 1)
     new = {"k": k, "v": v}
@@ -343,8 +403,8 @@ def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window,
     else:
         attn_out = layers.decode_attention(q, k_cache, v_cache, cache_len,
                                            softcap=cfg.logit_softcap)
-    h = h + layers.out_project(p["attn"], cfg, attn_out)
-    x = layers.apply_norm(p["ffn_norm"], h, cfg.norm)
+    h = h + _res(cfg, layers.out_project(p["attn"], cfg, attn_out))
+    x = _norm(cfg, p["ffn_norm"], h)
     if cfg.moe is not None:
         if plan.moe_impl == "shardmap_ep":
             y, _ = moe_mod.apply_moe_ep(p["ffn"], cfg, x, rules,
@@ -357,39 +417,66 @@ def _decode_dense_block(p, cfg, plan, rules, h, cache, pos, window,
         y = layers.apply_ffn(p["ffn"], x, cfg.ffn_act, cfg.use_bias)
     if layer is None:
         cache = dict(cache, k=k_cache, v=v_cache)
-    return h + y, cache
+    return h + _res(cfg, y), cache
 
 
 def _apply_cross_block(p, cfg, plan, rules, h, ctx, collect=False):
-    x = layers.apply_norm(p["attn_norm"], h, cfg.norm)
+    x = _norm(cfg, p["attn_norm"], h)
     q = layers.q_project(p["attn"], cfg, x)
     k, v = layers.kv_project(p["attn"], cfg, ctx)
     attn_out = layers.dense_attention(q, k, v, causal=False)
     h = h + layers.out_project(p["attn"], cfg, attn_out)
-    x = layers.apply_norm(p["ffn_norm"], h, cfg.norm)
+    x = _norm(cfg, p["ffn_norm"], h)
     h = h + layers.apply_ffn(p["ffn"], x, cfg.ffn_act, cfg.use_bias)
     return (h, (k, v)) if collect else (h, None)
 
 
 def _apply_cross_block_cached(p, cfg, rules, h, kc, vc):
-    x = layers.apply_norm(p["attn_norm"], h, cfg.norm)
+    x = _norm(cfg, p["attn_norm"], h)
     q = layers.q_project(p["attn"], cfg, x)
     attn_out = layers.decode_attention(q, kc, vc, jnp.int32(kc.shape[1]))
     h = h + layers.out_project(p["attn"], cfg, attn_out)
-    x = layers.apply_norm(p["ffn_norm"], h, cfg.norm)
+    x = _norm(cfg, p["ffn_norm"], h)
     return h + layers.apply_ffn(p["ffn"], x, cfg.ffn_act, cfg.use_bias)
 
 
 def _apply_recurrent_block(bp, cfg, plan, rules, h, collect=False):
-    x = layers.apply_norm(bp["norm"], h, cfg.norm)
+    x = _norm(cfg, bp["norm"], h)
     if collect:
         y, st = rglru.apply_rglru(bp["lru"], cfg, x, rules, return_state=True)
     else:
         y, st = rglru.apply_rglru(bp["lru"], cfg, x, rules), None
     h = h + y
-    x = layers.apply_norm(bp["ffn_norm"], h, cfg.norm)
+    x = _norm(cfg, bp["ffn_norm"], h)
     h = h + layers.apply_ffn(bp["ffn"], x, cfg.ffn_act, cfg.use_bias)
     return h, st
+
+
+def _apply_mamba_block(bp, cfg, plan, rules, h, collect=False):
+    x = _norm(cfg, bp["norm"], h)
+    kw = dict(chunk=plan.ssd_chunk, bf16=plan.ssd_bf16)
+    if collect:
+        y, st = ssm_mod.apply_ssm(bp["ssm"], cfg, x, rules,
+                                  return_state=True, **kw)
+    else:
+        y, st = ssm_mod.apply_ssm(bp["ssm"], cfg, x, rules, **kw), None
+    h = h + _res(cfg, y)
+    x = _norm(cfg, bp["ffn_norm"], h)
+    h = h + _res(cfg, layers.apply_ffn(bp["ffn"], x, cfg.ffn_act,
+                                       cfg.use_bias))
+    return h, st
+
+
+def _apply_hybrid_block(kind, bp, cfg, plan, rules, h, positions,
+                        collect=False):
+    """One block of a hybrid; returns (h, its collected state or K/V)."""
+    if kind == "recurrent":
+        return _apply_recurrent_block(bp, cfg, plan, rules, h, collect)
+    if kind == "mamba":
+        return _apply_mamba_block(bp, cfg, plan, rules, h, collect)
+    h, _, kv = _apply_dense_block(bp, cfg, plan, rules, h, positions,
+                                  _hybrid_window(cfg, kind), collect)
+    return h, kv
 
 
 # ===========================================================================
@@ -457,36 +544,24 @@ def _backbone(cfg, plan, rules, params, h, positions, batch, collect=False):
             hh, aux = carry
             out = {}
             for i, kind in enumerate(pat):
-                if kind == "recurrent":
-                    hh, st = _apply_recurrent_block(gp[f"b{i}"], cfg, plan,
-                                                    rules, hh, collect)
-                    out[f"b{i}"] = st
-                else:
-                    hh, _, kv = _apply_dense_block(gp[f"b{i}"], cfg, plan,
-                                                   rules, hh, positions,
-                                                   cfg.window, collect)
-                    out[f"b{i}"] = kv
+                hh, out[f"b{i}"] = _apply_hybrid_block(
+                    kind, gp[f"b{i}"], cfg, plan, rules, hh, positions,
+                    collect)
             return (hh, aux), out
 
         (h, aux), collected = jax.lax.scan(_maybe_remat(group_body, plan),
                                            (h, aux0), params["blocks"])
         tail_out = []
         for i, bp in enumerate(params.get("tail", [])):
-            kind = pat[i % len(pat)]
-            if kind == "recurrent":
-                h, st = _apply_recurrent_block(bp, cfg, plan, rules, h,
-                                               collect)
-                tail_out.append(st)
-            else:
-                h, _, kv = _apply_dense_block(bp, cfg, plan, rules, h,
-                                              positions, cfg.window, collect)
-                tail_out.append(kv)
+            h, st = _apply_hybrid_block(pat[i % len(pat)], bp, cfg, plan,
+                                        rules, h, positions, collect)
+            tail_out.append(st)
         return h, aux, {"groups": collected, "tail": tail_out}
 
     if fam == "ssm":
         def body(carry, lp):
             hh, aux = carry
-            x = layers.apply_norm(lp["norm"], hh, cfg.norm)
+            x = _norm(cfg, lp["norm"], hh)
             if collect:
                 y, st = ssm_mod.apply_ssm(lp["ssm"], cfg, x, rules,
                                           return_state=True,
@@ -510,20 +585,20 @@ def encode_audio(cfg, plan, rules, params, batch):
     pos = jnp.arange(enc.shape[1])
 
     def body(hh, lp):
-        x = layers.apply_norm(lp["attn_norm"], hh, cfg.norm)
+        x = _norm(cfg, lp["attn_norm"], hh)
         q = layers.q_project(lp["attn"], cfg, x)
         k, v = layers.kv_project(lp["attn"], cfg, x)
         q = layers.apply_rope(q, pos, cfg.rope_theta)
         k = layers.apply_rope(k, pos, cfg.rope_theta)
         hh = hh + layers.out_project(
             lp["attn"], cfg, layers.dense_attention(q, k, v, causal=False))
-        x = layers.apply_norm(lp["ffn_norm"], hh, cfg.norm)
+        x = _norm(cfg, lp["ffn_norm"], hh)
         return hh + layers.apply_ffn(lp["ffn"], x, cfg.ffn_act,
                                      cfg.use_bias), None
 
     enc, _ = jax.lax.scan(_maybe_remat(body, plan), enc,
                           params["enc_blocks"])
-    return layers.apply_norm(params["enc_norm"], enc, cfg.norm)
+    return _norm(cfg, params["enc_norm"], enc)
 
 
 # ---------------------------------------------------------------------------
@@ -532,6 +607,11 @@ def encode_audio(cfg, plan, rules, params, batch):
 
 def _unembed_matrix(cfg, params):
     return params["embed"].T if cfg.tie_embeddings else params["unembed"]
+
+
+def _scale_logits(cfg, logits):
+    m = cfg.logits_scaling
+    return logits if m == 1.0 else logits / m
 
 
 def chunked_softmax_xent(cfg, plan, rules, params, hidden, labels):
@@ -549,7 +629,8 @@ def chunked_softmax_xent(cfg, plan, rules, params, hidden, labels):
     @jax.checkpoint
     def step(acc, inp):
         hh, ll = inp                            # [b,chunk,d], [b,chunk]
-        logits = jnp.einsum("bcd,dv->bcv", hh, w).astype(jnp.float32)
+        logits = _scale_logits(
+            cfg, jnp.einsum("bcd,dv->bcv", hh, w).astype(jnp.float32))
         logits = rules.constrain(logits, ("batch", None, "vocab"))
         if cfg.padded_vocab != cfg.vocab_size:
             pad = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
@@ -566,7 +647,8 @@ def chunked_softmax_xent(cfg, plan, rules, params, hidden, labels):
 def logits_for(cfg, rules, params, hidden):
     """Full logits for a short hidden slice (decode / last position)."""
     w = _unembed_matrix(cfg, params)
-    logits = jnp.einsum("bsd,dv->bsv", hidden, w).astype(jnp.float32)
+    logits = _scale_logits(
+        cfg, jnp.einsum("bsd,dv->bsv", hidden, w).astype(jnp.float32))
     logits = rules.constrain(logits, ("batch", None, "vocab"))
     if cfg.padded_vocab != cfg.vocab_size:
         pad = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
@@ -616,21 +698,24 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
     if fam == "hybrid":
         pat = cfg.hybrid.pattern
         groups, tail = hybrid_groups(cfg)
-        c: Params = {}
-        for i, kind in enumerate(pat):
+
+        def block_cache(kind, *lead):
             if kind == "recurrent":
                 base = rglru.init_rglru_cache(cfg, batch, dtype)
-                c[f"b{i}"] = jax.tree.map(
-                    lambda x: jnp.zeros((groups,) + x.shape, x.dtype), base)
+            elif kind == "mamba":
+                base = ssm_mod.init_ssm_cache(cfg, batch, dtype)
             else:
-                c[f"b{i}"] = kv_buf_plain(min(seq_len, cfg.window), groups)
-        out = {"groups": c}
+                w = _hybrid_window(cfg, kind)
+                return kv_buf_plain(min(seq_len, w) if w else seq_len,
+                                    *lead)
+            return jax.tree.map(
+                lambda x: jnp.zeros(tuple(lead) + x.shape, x.dtype), base)
+
+        out = {"groups": {f"b{i}": block_cache(kind, groups)
+                          for i, kind in enumerate(pat)}}
         if tail:
-            out["tail"] = [
-                (rglru.init_rglru_cache(cfg, batch, dtype)
-                 if pat[i % len(pat)] == "recurrent"
-                 else kv_buf_plain(min(seq_len, cfg.window)))
-                for i in range(tail)]
+            out["tail"] = [block_cache(pat[i % len(pat)])
+                           for i in range(tail)]
         return out
     if fam == "ssm":
         base = ssm_mod.init_ssm_cache(cfg, batch, dtype)
@@ -655,6 +740,15 @@ def cache_axes(cfg: ModelConfig, quant: bool = False) -> Params:
     def rec_axes(*lead):
         return {"conv": tuple(lead) + ("batch", None, "lru"),
                 "h": tuple(lead) + ("batch", None, "lru")}
+
+    def ssm_axes(*lead):
+        return {"conv": tuple(lead) + ("batch", None, "lru"),
+                "state": tuple(lead) + ("batch", "heads", None, None)}
+
+    def hybrid_axes(kind, *lead):
+        if kind == "recurrent":
+            return rec_axes(*lead)
+        return ssm_axes(*lead) if kind == "mamba" else kvbuf(*lead)
     fam = cfg.family
     if fam in ("dense", "moe"):
         return {"attn": kvbuf("layers")}
@@ -665,21 +759,15 @@ def cache_axes(cfg: ModelConfig, quant: bool = False) -> Params:
         return {"attn": kvbuf("layers"), "cross": kvbuf_plain("layers")}
     if fam == "hybrid":
         pat = cfg.hybrid.pattern
-        groups_axes = {
-            f"b{i}": (rec_axes("layers") if kind == "recurrent"
-                      else kvbuf("layers"))
-            for i, kind in enumerate(pat)}
-        out = {"groups": groups_axes}
+        out = {"groups": {f"b{i}": hybrid_axes(kind, "layers")
+                          for i, kind in enumerate(pat)}}
         _, tail = hybrid_groups(cfg)
         if tail:
-            out["tail"] = [
-                (rec_axes() if pat[i % len(pat)] == "recurrent" else kvbuf())
-                for i in range(tail)]
+            out["tail"] = [hybrid_axes(pat[i % len(pat)])
+                           for i in range(tail)]
         return out
     if fam == "ssm":
-        return {"blocks": {"conv": ("layers", "batch", None, "lru"),
-                           "state": ("layers", "batch", "heads", None,
-                                     None)}}
+        return {"blocks": ssm_axes("layers")}
     raise ValueError(fam)
 
 
@@ -721,9 +809,12 @@ def assemble_cache(cfg, collected, batch_size, seq_len, cache_len,
         return {"k": _ring_place(k, kvl, seq_len, dtype),
                 "v": _ring_place(v, kvl, seq_len, dtype)}
 
-    def place_win(kv):
-        k, v = kv
-        w = min(cache_len, cfg.window)
+    def place_block(kind, st):
+        if kind in ("recurrent", "mamba"):
+            return st
+        k, v = st
+        w = _hybrid_window(cfg, kind)
+        w = min(cache_len, w) if w else cache_len
         return {"k": _ring_place(k, w, seq_len, dtype),
                 "v": _ring_place(v, w, seq_len, dtype)}
 
@@ -742,16 +833,12 @@ def assemble_cache(cfg, collected, batch_size, seq_len, cache_len,
                 "cross": cross(collected["cross"])}
     if fam == "hybrid":
         pat = cfg.hybrid.pattern
-        groups = {}
-        for i, kind in enumerate(pat):
-            groups[f"b{i}"] = (collected["groups"][f"b{i}"]
-                               if kind == "recurrent"
-                               else place_win(collected["groups"][f"b{i}"]))
-        out = {"groups": groups}
+        out = {"groups": {f"b{i}": place_block(kind,
+                                               collected["groups"][f"b{i}"])
+                          for i, kind in enumerate(pat)}}
         if collected.get("tail"):
-            out["tail"] = [
-                (st if pat[i % len(pat)] == "recurrent" else place_win(st))
-                for i, st in enumerate(collected["tail"])]
+            out["tail"] = [place_block(pat[i % len(pat)], st)
+                           for i, st in enumerate(collected["tail"])]
         return out
     if fam == "ssm":
         return {"blocks": collected["blocks"]}
@@ -786,17 +873,58 @@ def init_cache_with_context(cfg, plan, rules, params, batch, batch_size,
 # decode
 # ===========================================================================
 
+def _decode_recurrent_block(bp, cfg, rules, h, cache):
+    x = _norm(cfg, bp["norm"], h)
+    y, nc = rglru.decode_rglru(bp["lru"], cfg, x, cache, rules)
+    h = h + y
+    x = _norm(cfg, bp["ffn_norm"], h)
+    return h + layers.apply_ffn(bp["ffn"], x, cfg.ffn_act, cfg.use_bias), nc
+
+
+def _decode_mamba_block(bp, cfg, rules, h, cache):
+    x = _norm(cfg, bp["norm"], h)
+    y, nc = ssm_mod.decode_ssm(bp["ssm"], cfg, x, cache, rules)
+    h = h + _res(cfg, y)
+    x = _norm(cfg, bp["ffn_norm"], h)
+    return h + _res(cfg, layers.apply_ffn(bp["ffn"], x, cfg.ffn_act,
+                                          cfg.use_bias)), nc
+
+
+def _decode_hybrid_block(kind, bp, cfg, plan, rules, h, cache, pos,
+                         layer=None):
+    """One hybrid block's decode step.  A recurrent or Mamba block's cache
+    is its own state; an attention block's is its K/V, or with ``layer``
+    the whole stack, as in ``_decode_dense_block``."""
+    if kind == "recurrent":
+        return _decode_recurrent_block(bp, cfg, rules, h, cache)
+    if kind == "mamba":
+        return _decode_mamba_block(bp, cfg, rules, h, cache)
+    return _decode_dense_block(bp, cfg, plan, rules, h, cache, pos,
+                               _hybrid_window(cfg, kind), layer=layer)
+
+
+def per_row_positions(cfg) -> bool:
+    """Whether ``decode_forward`` takes a position per row: a plain KV
+    stack, or a hybrid of whole periods (every cache leaf stacked by
+    group, the batch on axis 1)."""
+    if cfg.family in ("dense", "moe"):
+        return True
+    return cfg.family == "hybrid" and hybrid_groups(cfg)[1] == 0
+
+
 def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
     """One decode step. tokens [B,1] int32; pos the absolute position, a
-    scalar for the whole batch or int32[B], one per row (dense and moe:
-    each row attends to and writes at its own position)."""
+    scalar for the whole batch or int32[B], one per row (where
+    ``per_row_positions``: each row attends to and writes at its own
+    position)."""
     h = _embed(cfg, rules, params, tokens)
     fam = cfg.family
     window = _window_of(cfg)
 
-    if jnp.ndim(pos) and fam not in ("dense", "moe"):
+    if jnp.ndim(pos) and not per_row_positions(cfg):
         raise ValueError(f"a position per row needs a plain KV stack "
-                         f"(dense or moe), not family {fam!r}")
+                         f"(dense or moe) or a hybrid of whole periods, "
+                         f"not {cfg.name!r} (family {fam!r})")
 
     if fam in ("dense", "moe") and jnp.ndim(pos):
         # the stack rides in the carry, not in xs/ys: each layer writes its
@@ -853,6 +981,35 @@ def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
         h, (new_attn, new_cross) = jax.lax.scan(
             body, h, (params["dec_blocks"], cache["attn"], cache["cross"]))
         new_cache = {"attn": new_attn, "cross": new_cross}
+    elif fam == "hybrid" and jnp.ndim(pos):
+        pat = cfg.hybrid.pattern
+
+        # as for dense: the caches ride in the carry, each block writing
+        # its group's entry in place — an attention block one K/V row per
+        # slot, a recurrent or Mamba block its whole state
+        def group_body(carry, xs):
+            hh, gc = carry
+            gp, g = xs
+            gc = dict(gc)
+            for i, kind in enumerate(pat):
+                name = f"b{i}"
+                if kind in ("recurrent", "mamba"):
+                    st = jax.tree.map(lambda c: c[g], gc[name])
+                    hh, st = _decode_hybrid_block(kind, gp[name], cfg, plan,
+                                                  rules, hh, st, pos)
+                    gc[name] = jax.tree.map(
+                        lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                            c, n.astype(c.dtype), g, 0), gc[name], st)
+                else:
+                    hh, gc[name] = _decode_hybrid_block(
+                        kind, gp[name], cfg, plan, rules, hh, gc[name], pos,
+                        layer=g)
+            return (hh, gc), None
+
+        (h, new_groups), _ = jax.lax.scan(
+            group_body, (h, cache["groups"]),
+            (params["blocks"], jnp.arange(hybrid_groups(cfg)[0])))
+        new_cache = {"groups": new_groups}
     elif fam == "hybrid":
         pat = cfg.hybrid.pattern
 
@@ -860,20 +1017,9 @@ def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
             gp, gc = xs
             new_gc = {}
             for i, kind in enumerate(pat):
-                bp = gp[f"b{i}"]
-                if kind == "recurrent":
-                    x = layers.apply_norm(bp["norm"], hh, cfg.norm)
-                    y, nc = rglru.decode_rglru(bp["lru"], cfg, x,
-                                               gc[f"b{i}"], rules)
-                    hh = hh + y
-                    x = layers.apply_norm(bp["ffn_norm"], hh, cfg.norm)
-                    hh = hh + layers.apply_ffn(bp["ffn"], x, cfg.ffn_act,
-                                               cfg.use_bias)
-                else:
-                    hh, nc = _decode_dense_block(bp, cfg, plan, rules, hh,
-                                                 gc[f"b{i}"], pos,
-                                                 cfg.window)
-                new_gc[f"b{i}"] = nc
+                hh, new_gc[f"b{i}"] = _decode_hybrid_block(
+                    kind, gp[f"b{i}"], cfg, plan, rules, hh, gc[f"b{i}"],
+                    pos)
             return hh, new_gc
 
         h, new_groups = jax.lax.scan(group_body, h,
@@ -882,24 +1028,15 @@ def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
         if "tail" in params:
             new_tail = []
             for i, bp in enumerate(params["tail"]):
-                kind = pat[i % len(pat)]
-                tc = cache["tail"][i]
-                if kind == "recurrent":
-                    x = layers.apply_norm(bp["norm"], h, cfg.norm)
-                    y, nc = rglru.decode_rglru(bp["lru"], cfg, x, tc, rules)
-                    h = h + y
-                    x = layers.apply_norm(bp["ffn_norm"], h, cfg.norm)
-                    h = h + layers.apply_ffn(bp["ffn"], x, cfg.ffn_act,
-                                             cfg.use_bias)
-                else:
-                    h, nc = _decode_dense_block(bp, cfg, plan, rules, h, tc,
-                                                pos, cfg.window)
+                h, nc = _decode_hybrid_block(pat[i % len(pat)], bp, cfg,
+                                             plan, rules, h,
+                                             cache["tail"][i], pos)
                 new_tail.append(nc)
             new_cache["tail"] = new_tail
     elif fam == "ssm":
         def body(hh, xs):
             lp, lc = xs
-            x = layers.apply_norm(lp["norm"], hh, cfg.norm)
+            x = _norm(cfg, lp["norm"], hh)
             y, nc = ssm_mod.decode_ssm(lp["ssm"], cfg, x, lc, rules)
             return hh + y, nc
 
@@ -909,7 +1046,7 @@ def decode_forward(cfg, plan, rules, params, cache, tokens, pos):
     else:
         raise ValueError(fam)
 
-    h = layers.apply_norm(params["final_norm"], h, cfg.norm)
+    h = _norm(cfg, params["final_norm"], h)
     logits = logits_for(cfg, rules, params, h)[:, 0]
     return logits, new_cache
 
@@ -939,7 +1076,7 @@ class Model:
         h = _embed(cfg, rules, params, tokens)
         positions = jnp.arange(tokens.shape[1])
         h, aux, _ = _backbone(cfg, plan, rules, params, h, positions, batch)
-        h = layers.apply_norm(params["final_norm"], h, cfg.norm)
+        h = _norm(cfg, params["final_norm"], h)
         loss = chunked_softmax_xent(cfg, plan, rules, params, h,
                                     batch["labels"])
         total = loss + 0.01 * aux
@@ -954,7 +1091,7 @@ class Model:
         positions = jnp.arange(s)
         h, _, collected = _backbone(cfg, plan, rules, params, h, positions,
                                     batch, collect=True)
-        h = layers.apply_norm(params["final_norm"], h, cfg.norm)
+        h = _norm(cfg, params["final_norm"], h)
         last = logits_for(cfg, rules, params, h[:, -1:])[:, 0]
         cache = assemble_cache(cfg, collected, b, s, cache_len,
                                quant=plan.kv_cache_quant)

@@ -5,6 +5,10 @@ TPU adaptation: the SSD algorithm is expressed as chunk-local masked matmuls
 exactly the "matrix-form" duality from arXiv:2405.21060 — no per-token scan,
 so the MXU does nearly all the FLOPs and the recurrence touches only the
 [H, N, P] chunk states.
+
+A prompt of any length runs: the last chunk is padded with ``dt = 0`` and
+``x = 0``, which leaves the state as it was (``exp(0 * A) = 1`` and no
+input), and the padded outputs are dropped (``chunk_layout``).
 """
 from __future__ import annotations
 
@@ -27,6 +31,8 @@ def init_ssm(key, cfg, dtype):
         "w_in": layers.dense_init(ks[0], (d, in_dim), d, dtype),
         "conv_w": (jax.random.normal(ks[1], (s.conv_kernel, di + 2 * gn),
                                      jnp.float32) * 0.1).astype(dtype),
+        **({"conv_b": jnp.zeros((di + 2 * gn,), dtype)} if s.conv_bias
+           else {}),
         "A_log": jnp.log(jnp.linspace(1.0, float(nh), nh)).astype(jnp.float32),
         "D": jnp.ones((nh,), jnp.float32),
         "dt_bias": jnp.zeros((nh,), jnp.float32),
@@ -40,6 +46,7 @@ def ssm_axes(cfg):
     return {
         "w_in": ("embed", "lru"),
         "conv_w": (None, "lru"),
+        **({"conv_b": ("lru",)} if cfg.ssm.conv_bias else {}),
         "A_log": (None,),
         "D": (None,),
         "dt_bias": (None,),
@@ -58,8 +65,9 @@ def _split_in(cfg, h):
     return z, x, b_, c_, dt, di, gn, nh
 
 
-def _causal_conv(x, w, state=None):
-    """x [B,S,C], w [K,C] depthwise causal conv. state [B,K-1,C] for decode."""
+def _causal_conv(x, w, state=None, bias=None):
+    """x [B,S,C], w [K,C] depthwise causal conv (``bias`` [C] or None).
+    state [B,K-1,C] for decode."""
     k = w.shape[0]
     if state is None:
         pad = jnp.zeros((x.shape[0], k - 1, x.shape[2]), x.dtype)
@@ -67,23 +75,43 @@ def _causal_conv(x, w, state=None):
         pad = state.astype(x.dtype)
     xp = jnp.concatenate([pad, x], axis=1)
     out = sum(xp[:, i:i + x.shape[1]] * w[i] for i in range(k))
+    if bias is not None:
+        out = out + bias
     new_state = xp[:, -(k - 1):] if k > 1 else pad
     return jax.nn.silu(out), new_state
 
 
+def _gated_norm(p, cfg, y, z):
+    """Mamba-2's gated RMSNorm: norm(y * silu(z)), in float32."""
+    y = y * jax.nn.silu(z)
+    yf = y.astype(jnp.float32)
+    return (yf * jax.lax.rsqrt(jnp.mean(jnp.square(yf), -1, keepdims=True)
+                               + cfg.norm_eps)
+            * p["norm_scale"].astype(jnp.float32)).astype(y.dtype)
+
+
+def chunk_layout(cfg, seq_len: int, chunk: int = 0):
+    """How ``apply_ssm`` cuts ``seq_len`` positions: (chunk length, number
+    of chunks, positions padded in the last chunk).  A sequence shorter
+    than the chunk is one chunk of its own length."""
+    q = min(chunk or cfg.ssm.chunk, seq_len)
+    pad = -seq_len % q
+    return q, (seq_len + pad) // q, pad
+
+
 def apply_ssm(p, cfg, hidden, rules, return_state=False, chunk=0,
               bf16=False):
-    """Training/prefill path. hidden [B,S,D] -> [B,S,D]."""
+    """Training/prefill path. hidden [B,S,D] -> [B,S,D], any S."""
     s = cfg.ssm
     b, S, _ = hidden.shape
-    q = min(chunk or s.chunk, S)
-    assert S % q == 0, f"seq {S} must divide chunk {q}"
-    nc = S // q
+    q, nc, npad = chunk_layout(cfg, S, chunk)
 
     h = jnp.einsum("bsd,de->bse", hidden, p["w_in"])
     z, x, B_, C_, dt, di, gn, nh = _split_in(cfg, h)
     conv_in = jnp.concatenate([x, B_, C_], -1)
-    xbc, conv_state = _causal_conv(conv_in, p["conv_w"])
+    # the conv runs over the real positions: its state is their last K-1
+    xbc, conv_state = _causal_conv(conv_in, p["conv_w"],
+                                   bias=p.get("conv_b"))
     x, B_, C_ = jnp.split(xbc, [di, di + gn], axis=-1)
 
     P = s.headdim
@@ -98,8 +126,13 @@ def apply_ssm(p, cfg, hidden, rules, return_state=False, chunk=0,
     Ch = jnp.repeat(C_, rep, axis=2)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # [b,S,nh]
+    if npad:
+        # padded positions: dt = 0 and x = 0 leave the state unchanged
+        def tail(a):
+            return jnp.pad(a, [(0, 0), (0, npad)] + [(0, 0)] * (a.ndim - 2))
+        x, Bh, Ch, dt = tail(x), tail(Bh), tail(Ch), tail(dt)
     A = -jnp.exp(p["A_log"])                                      # [nh]
-    dA = dt * A                                                   # [b,S,nh] (log-decay)
+    dA = dt * A                                                   # [b,Sp,nh] (log-decay)
 
     # chunk
     xc = x.reshape(b, nc, q, nh, P)
@@ -145,15 +178,13 @@ def apply_ssm(p, cfg, hidden, rules, return_state=False, chunk=0,
     y_inter = jnp.einsum("bcihn,bchnp->bcihp",
                          Cc.astype(jnp.float32) * jnp.exp(cum)[..., None],
                          prev_states)
-    y = (y_diag + y_inter).reshape(b, S, nh, P)
+    y = (y_diag + y_inter).reshape(b, nc * q, nh, P)
     y = y + x.astype(jnp.float32) * p["D"][None, None, :, None]
+    if npad:
+        y = y[:, :S]
     y = y.reshape(b, S, di).astype(hidden.dtype)
 
-    # gated RMSNorm (Mamba-2 style): norm(y * silu(z))
-    y = y * jax.nn.silu(z)
-    yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(jnp.square(yf), -1, keepdims=True) + 1e-6)
-         * p["norm_scale"].astype(jnp.float32)).astype(hidden.dtype)
+    y = _gated_norm(p, cfg, y, z)
     out = jnp.einsum("bse,ed->bsd", y, p["w_out"])
     if return_state:
         return out, {"conv": conv_state.astype(hidden.dtype),
@@ -179,7 +210,8 @@ def decode_ssm(p, cfg, hidden, cache, rules):
     h = jnp.einsum("bsd,de->bse", hidden, p["w_in"])
     z, x, B_, C_, dt, di, gn, nh = _split_in(cfg, h)
     xbc, conv_state = _causal_conv(
-        jnp.concatenate([x, B_, C_], -1), p["conv_w"], cache["conv"])
+        jnp.concatenate([x, B_, C_], -1), p["conv_w"], cache["conv"],
+        p.get("conv_b"))
     x, B_, C_ = jnp.split(xbc, [di, di + gn], axis=-1)
     P, N, G = s.headdim, s.d_state, s.n_groups
     rep = nh // G
@@ -194,9 +226,6 @@ def decode_ssm(p, cfg, hidden, cache, rules):
     y = jnp.einsum("bhn,bhnp->bhp", Ch.astype(jnp.float32), st)
     y = y + x.astype(jnp.float32) * p["D"][None, :, None]
     y = y.reshape(b, 1, di).astype(hidden.dtype)
-    y = y * jax.nn.silu(z)
-    yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(jnp.square(yf), -1, keepdims=True) + 1e-6)
-         * p["norm_scale"].astype(jnp.float32)).astype(hidden.dtype)
+    y = _gated_norm(p, cfg, y, z)
     out = jnp.einsum("bse,ed->bsd", y, p["w_out"])
     return out, {"conv": conv_state, "state": st}

@@ -9,11 +9,14 @@ shapes, so the step compiles exactly once per engine (pinned by
 The pool takes one of two forms, chosen once from the model's layer type
 (``ContinuousBatcher.pool_path``):
 
-  * ``"inplace"`` — a dense model with a plain KV stack: the pool is
-    ``init_cache(cfg, n_slots, cache_len)``, its batch axis the slot axis
-    (``[L, S, W, KV, Dh]``), and the step is ``Model.decode_step`` with a
-    position per slot, which writes one K/V row per slot and layer;
-  * ``"vmap"`` — recurrent (ssm/hybrid), MoE, vlm and audio models: one
+  * ``"inplace"`` — a dense model with a plain KV stack, or a hybrid of
+    whole periods (Granite 4.0-H: Mamba-2 and attention blocks): the pool
+    is ``init_cache(cfg, n_slots, cache_len)``, its batch axis the slot
+    axis (dense ``[L, S, W, KV, Dh]``; a hybrid's leaves ``[groups, S,
+    ...]``), and the step is ``Model.decode_step`` with a position per
+    slot, which writes one K/V row per slot and attention layer and
+    rewrites each recurrent layer's state;
+  * ``"vmap"`` — ssm, other hybrids, MoE, vlm and audio models: one
     batch-1 cache per slot stacked on a new leading axis, stepped by
     ``vmap(decode_step)``.
 
@@ -46,12 +49,17 @@ on the first token's logits, ``insert`` dispatch), ``decode`` (staging and
 dispatch of the pool step), ``token_wait`` (reading the step's tokens
 back) and ``emit`` (per-slot bookkeeping, metrics callbacks, retirement).
 ``submit`` records a ``submit`` event with the request's ``rid``, which
-its ``admit`` span carries too.
+its ``admit`` span carries too.  Counters from shapes, free on the device:
+``prefill`` carries ``ssd_chunks`` and ``ssd_pad`` (positions padded in the
+last SSD chunk) where the model has Mamba-2 layers; ``insert`` carries a
+slot's ``state_bytes`` (recurrent) and ``kv_bytes``; ``decode`` the
+``state_bytes`` of recurrent state the step rewrites, every slot's.
 """
 from __future__ import annotations
 
+import math
 import zlib
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -60,6 +68,23 @@ from repro.serve.metrics import ServeMetrics
 from repro.serve.request import Request
 
 DEFAULT_TICK_S = 0.01
+
+
+KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+
+def slot_state_bytes(pool, n_slots: int) -> Tuple[int, int]:
+    """One slot's share of the pool: (recurrent state bytes, K/V bytes).
+    A leaf named as a K/V buffer is K/V; any other is recurrent state."""
+    import jax
+    state = kv = 0
+    for path, x in jax.tree_util.tree_flatten_with_path(pool)[0]:
+        n = math.prod(x.shape) * x.dtype.itemsize // n_slots
+        if getattr(path[-1], "key", None) in KV_LEAVES:
+            kv += n
+        else:
+            state += n
+    return state, kv
 
 
 def synth_tokens(rid: str, prompt_len: int, vocab: int) -> np.ndarray:
@@ -85,7 +110,7 @@ class ContinuousBatcher:
                  tick_s: float = DEFAULT_TICK_S):
         import jax
         import jax.numpy as jnp
-        from repro.models.lm import init_cache
+        from repro.models.lm import init_cache, per_row_positions
 
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1: {n_slots}")
@@ -108,12 +133,13 @@ class ContinuousBatcher:
         # half of the zero-recompile guarantee
         self.traces = {"decode_step": 0, "insert": 0, "prefill": 0}
 
-        # a plain KV stack batches its slots: the pool is one decode cache
-        # whose batch axis is the slot, stepped with a position per slot
-        # and updated in place.  Recurrent state has no row to write, and
-        # batching a MoE model's slots changes what its capacity drops:
-        # those keep one batch-1 cache per slot under vmap.
-        inplace = self.cfg.family == "dense" and self.cfg.moe is None
+        # a plain KV stack, or a hybrid whose every cache leaf is stacked
+        # by group, batches its slots: the pool is one decode cache whose
+        # batch axis is the slot, stepped with a position per slot and
+        # updated in place.  Batching a MoE model's slots changes what its
+        # capacity drops; other families keep one batch-1 cache per slot
+        # under vmap.
+        inplace = self.cfg.moe is None and per_row_positions(self.cfg)
         self.pool_path = "inplace" if inplace else "vmap"
         quant = model.plan.kv_cache_quant
         if inplace:
@@ -146,6 +172,12 @@ class ContinuousBatcher:
                     return jax.lax.dynamic_update_index_in_dim(p, o, idx, 0)
             return jax.tree.map(lambda p, o: put(p, o.astype(p.dtype)),
                                 pool, one_cache)
+
+        self.slot_state_bytes, self.slot_kv_bytes = slot_state_bytes(
+            self._pool, self.n_slots)
+        ssm_layers = self.cfg.ssm is not None and (
+            self.cfg.family == "ssm" or "mamba" in self.cfg.hybrid.pattern)
+        self._ssd_chunk = model.plan.ssd_chunk if ssm_layers else None
 
         # the pool is donated to both: each returns it updated in place
         self._step = jax.jit(pool_step, donate_argnums=1)
@@ -213,12 +245,20 @@ class ContinuousBatcher:
         for k, v in req.extras.items():
             batch[k] = v
         tracer, track = get_tracer(), self._track
-        with tracer.span("prefill", cat="engine", track=track):
+        ssd = {}
+        if self._ssd_chunk is not None:
+            from repro.models.ssm import chunk_layout
+            _, n, pad = chunk_layout(self.cfg, req.prompt_len,
+                                     self._ssd_chunk)
+            ssd = {"ssd_chunks": n, "ssd_pad": pad}
+        with tracer.span("prefill", cat="engine", track=track, **ssd):
             logits, cache = self._prefill_fn(req.prompt_len)(self.params,
                                                              batch)
         with tracer.span("first_token_wait", cat="engine", track=track):
             first = int(np.asarray(logits).argmax(axis=-1)[0])
-        with tracer.span("insert", cat="engine", track=track):
+        with tracer.span("insert", cat="engine", track=track,
+                         state_bytes=self.slot_state_bytes,
+                         kv_bytes=self.slot_kv_bytes):
             self._pool = self._insert(self._pool, cache, slot)
         self._active[slot] = True
         self._pos[slot] = req.prompt_len
@@ -278,7 +318,9 @@ class ContinuousBatcher:
         live_before = [r.rid for r in self._slot_req if r is not None]
         if self._active.any():
             with tracer.span("decode", cat="engine", track=track,
-                             path=self.pool_path):
+                             path=self.pool_path,
+                             state_bytes=self.slot_state_bytes
+                             * self.n_slots):
                 toks = jnp.asarray(
                     self._last_tok.reshape(self.n_slots, 1, 1))
                 poss = jnp.asarray(self._pos)

@@ -12,6 +12,7 @@ FAMS = ["granite-3-2b",          # dense GQA
         "h2o-danube-1.8b",       # SWA
         "moonshot-v1-16b-a3b",   # MoE
         "recurrentgemma-2b",     # hybrid
+        "granite-4.0-h-micro",   # hybrid: Mamba-2 + NoPE attention
         "mamba2-1.3b",           # ssm
         "llama-3.2-vision-90b",  # vlm
         "seamless-m4t-medium"]   # audio enc-dec
@@ -68,6 +69,7 @@ def test_prefill_matches_incremental_decode(arch):
     ("granite-3-2b", 16, False),         # linear slot min(pos, w - 1)
     ("h2o-danube-1.8b", 8, False),       # ring slot pos % w
     ("granite-3-2b", 16, True),          # int8 cache with scales
+    ("granite-4.0-h-micro", 16, False),  # Mamba-2 state + NoPE K/V stack
 ])
 def test_vector_pos_matches_scalar_pos_row_by_row(arch, cache_len, quant):
     """decode_step with a position per row gives each row the logits and
@@ -105,6 +107,34 @@ def test_vector_pos_needs_a_plain_kv_stack():
     with pytest.raises(ValueError, match="plain KV stack"):
         model.decode_step(params, cache, jnp.zeros((2, 1), jnp.int32),
                           jnp.asarray([4, 4], jnp.int32))
+
+
+@pytest.mark.parametrize("seq", [13, 21])
+def test_ssd_padded_tail_matches_an_unpadded_run(seq):
+    """A prompt that is no multiple of the chunk (13 and 21 against 8)
+    pads its last chunk with dt = 0 and x = 0: its outputs and its final
+    conv and SSM states are those of one unpadded chunk of the whole
+    prompt (float32; the two differ only in the order of their sums)."""
+    import dataclasses
+    from repro.dist.sharding import NullRules
+    from repro.models import ssm
+    cfg = ARCHS["granite-4.0-h-micro"].reduced()
+    cfg = dataclasses.replace(cfg, ssm=dataclasses.replace(cfg.ssm, chunk=8))
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seq), 3)
+    p = ssm.init_ssm(k1, cfg, jnp.float32)
+    p["conv_b"] = jax.random.normal(k2, p["conv_b"].shape) * 0.1
+    x = jax.random.normal(k3, (2, seq, cfg.d_model))
+    assert ssm.chunk_layout(cfg, seq) == (8, -(-seq // 8), -seq % 8)
+    y, st = ssm.apply_ssm(p, cfg, x, NullRules(), return_state=True)
+    y1, st1 = ssm.apply_ssm(p, cfg, x, NullRules(), return_state=True,
+                            chunk=seq)
+    assert ssm.chunk_layout(cfg, seq, seq)[2] == 0
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y1), rtol=1e-5,
+                               atol=1e-5)
+    for name in ("conv", "state"):
+        np.testing.assert_allclose(np.asarray(st[name]),
+                                   np.asarray(st1[name]), rtol=1e-5,
+                                   atol=1e-5)
 
 
 def test_chunked_loss_matches_full_loss():

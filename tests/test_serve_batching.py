@@ -45,7 +45,8 @@ def sequential_reference(model, params, toks, prompt_len, gen, cache_len):
     (ARCH, 32, False),
     ("h2o-danube-1.8b", 12, False),      # window ring: slot pos % 12 wraps
     (ARCH, 32, True),                    # kv_cache_quant: int8 + scales
-], ids=["dense", "windowed", "int8kv"])
+    ("granite-4.0-h-micro", 32, False),  # Mamba-2 state + NoPE K/V stack
+], ids=["dense", "windowed", "int8kv", "hybrid"])
 def test_parity_with_midflight_joins_and_early_finishes(arch, cache_len,
                                                         quant):
     """The satellite pin: staggered arrivals (requests join while others
@@ -98,6 +99,7 @@ def _bytes(tree):
 
 @pytest.mark.parametrize("arch,path", [
     (ARCH, "inplace"),
+    ("granite-4.0-h-micro", "inplace"),
     ("mamba2-1.3b", "vmap"),
     ("recurrentgemma-2b", "vmap"),
     ("moonshot-v1-16b-a3b", "vmap"),
@@ -105,8 +107,9 @@ def _bytes(tree):
 def test_pool_is_donated_and_never_copied(arch, path):
     """The step and the insert take the pool donated and hand it back in
     place: the compiled programs alias at least the pool's bytes and
-    allocate no second pool.  Only a plain KV stack takes the in-place
-    pool; recurrent state and MoE keep one cache per slot under vmap."""
+    allocate no second pool.  Only a plain KV stack or a hybrid of whole
+    periods takes the in-place pool; other recurrent state and MoE keep
+    one cache per slot under vmap."""
     cfg, model, params = make_model(arch)
     engine = ContinuousBatcher(model, params, n_slots=4, cache_len=32)
     assert engine.pool_path == path
@@ -120,7 +123,9 @@ def test_pool_is_donated_and_never_copied(arch, path):
         ma = fn.lower(*args).compile().memory_analysis()
         assert ma.alias_size_in_bytes >= n
         assert ma.output_size_in_bytes - ma.alias_size_in_bytes < n
-    if path == "inplace":
+    if path == "inplace":       # the slot is every leaf's batch axis
+        assert all(x.shape[1] == 4 for x in jax.tree.leaves(pool))
+    if arch == ARCH:
         assert jax.tree.leaves(pool)[0].shape == (cfg.n_layers, 4, 32,
                                                   cfg.n_kv_heads,
                                                   cfg.head_dim)
@@ -275,3 +280,56 @@ def test_engine_spans_name_each_step_of_a_tick():
     assert sorted(a["attrs"]["rid"] for a in admits) == sorted(submits) \
         == ["r0", "r1", "r2"]
     assert all(a["t0"] >= submits[a["attrs"]["rid"]]["t"] for a in admits)
+
+
+def test_a_recycled_slot_keeps_no_state_of_its_last_occupant():
+    """The hybrid's insert replaces a slot's Mamba state and K/V whole: a
+    request served in a slot another request (longer, and ragged against
+    the SSD chunk) has just left gives the tokens it gives in a fresh
+    engine."""
+    cfg, model, params = make_model("granite-4.0-h-micro")
+    long_, short = prompts(cfg, 1, 21)[0], prompts(cfg, 1, 13, seed=2)[0]
+    engine = ContinuousBatcher(model, params, n_slots=1, cache_len=32)
+    engine.run([Request(rid="a", arch=cfg.name, prompt_len=21, max_gen=6,
+                        tokens=long_)])
+    out = engine.run([Request(rid="b", arch=cfg.name, prompt_len=13,
+                              max_gen=6, tokens=short)])["b"]
+    fresh = ContinuousBatcher(model, params, n_slots=1, cache_len=32)
+    want = fresh.run([Request(rid="b", arch=cfg.name, prompt_len=13,
+                              max_gen=6, tokens=short)])["b"]
+    assert np.array_equal(out, want)
+
+
+@pytest.mark.parametrize("arch", [ARCH, "granite-4.0-h-micro"])
+def test_spans_count_the_state_each_step_moves(arch):
+    """Counters from shapes: ``prefill`` carries the SSD's chunks and the
+    positions padded in the last one (Mamba layers only), ``insert`` a
+    slot's recurrent-state and K/V bytes, ``decode`` the recurrent state
+    the step rewrites over every slot."""
+    from repro.obs import Tracer, use_tracer
+    cfg, model, params = make_model(arch)
+    engine = ContinuousBatcher(model, params, n_slots=2, cache_len=32)
+    reqs = [Request(rid=f"r{n}", arch=cfg.name, prompt_len=n, max_gen=3,
+                    tokens=prompts(cfg, 1, n, seed=n)[0]) for n in (13, 21)]
+    tr = Tracer()
+    with use_tracer(tr):
+        engine.run(reqs)
+    spans = {n: [r for r in tr.records if r["type"] == "span"
+                 and r["name"] == n] for n in ("prefill", "insert", "decode")}
+    kv_row = 2 * cfg.n_kv_heads * cfg.head_dim * 4        # K and V, float32
+    if arch == ARCH:
+        assert all(r["attrs"] == {} for r in spans["prefill"])
+        state, kv = 0, cfg.n_layers * 32 * kv_row
+    else:
+        s, di = cfg.ssm, cfg.ssm.d_inner(cfg.d_model)
+        assert [(r["attrs"]["ssd_chunks"], r["attrs"]["ssd_pad"])
+                for r in spans["prefill"]] == [(1, 0), (2, 11)]
+        conv = (s.conv_kernel - 1) * (di + 2 * s.n_groups * s.d_state)
+        state = 9 * 4 * (s.n_heads(cfg.d_model) * s.d_state * s.headdim
+                         + conv)
+        kv = 32 * kv_row
+    assert (engine.slot_state_bytes, engine.slot_kv_bytes) == (state, kv)
+    assert all(r["attrs"]["state_bytes"] == state
+               and r["attrs"]["kv_bytes"] == kv for r in spans["insert"])
+    assert spans["decode"] and all(r["attrs"]["state_bytes"] == 2 * state
+                                   for r in spans["decode"])
